@@ -40,6 +40,7 @@ import json
 from repro.core.collm import CollmConfig
 from repro.core.transport import AsyncSimChannel, CloudServicePoint
 from repro.core.workload import ArrivalProcess, arrival_times
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.adaptive import (AdaptiveConfig, ResumeCostModel,
                                     WindowController)
 from repro.serving.engine import ServingSystem
@@ -231,6 +232,7 @@ def run_adaptive_pool(*, n_requests: int = 8, max_new: int = 16,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--engines", type=int, default=4)
     ap.add_argument("--requests", type=int, default=12)

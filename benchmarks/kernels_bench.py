@@ -159,7 +159,8 @@ def run(csv=True):
     # correctness cross-check (kernel interpret vs ref) on reduced shapes
     h = jax.random.normal(rng, (8, 128))
     w = jax.random.normal(jax.random.PRNGKey(4), (1024, 128)) * 0.05
-    c1, t1, _ = exit_confidence(h, w, jnp.zeros(128), block_v=256)
+    c1, t1, _ = exit_confidence(h, w, jnp.zeros(128), block_v=256,
+                                interpret=True)
     c2, t2, _ = exit_head_ref(h, w, jnp.zeros(128))
     assert bool(jnp.all(t1 == t2)) and float(jnp.max(jnp.abs(c1 - c2))) < 1e-5
     cf, tf, _, qf, sf = exit_quant(h, w, jnp.zeros(128), block_v=256,
@@ -192,4 +193,7 @@ def run(csv=True):
 
 if __name__ == "__main__":
     import json
+
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print(json.dumps(run(csv=False), indent=1))

@@ -9,6 +9,8 @@ import traceback
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (fig4_scaling, kernels_bench, roofline_table,
                             table2_deployment, table3_precision,
                             table4_ablation, throughput_bench)

@@ -72,6 +72,7 @@ from repro.core.collm import CollmConfig
 from repro.core.netsim import NetworkParams
 from repro.core.transport import (AsyncSimChannel, CloudServicePoint,
                                   ScriptedChannel)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.roofline.analyze import (decode_kv_bytes_per_token,
                                     hbm_roofline_fraction)
 from repro.serving.engine import ServingSystem
@@ -808,6 +809,7 @@ def run_cloud_tp(csv: bool = False, *, n_clients: int = 3, max_new: int = 8,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--clients", type=int, default=16)
     ap.add_argument("--max-new", type=int, default=24)

@@ -350,7 +350,7 @@ class CoLLM:
         return EdgeStepOut(decisions, tok, exited, upload, new_caches)
 
     def fused_exit_upload(self, params: Params, hidden: jax.Array, *,
-                          interpret: Optional[bool] = None,
+                          interpret: bool = False,
                           use_kernel: bool = True):
         """TPU hot path for the l_ee1 exit + upload: ONE Pallas launch
         (``kernels/exit_quant``) over the hidden tile computes the exit

@@ -9,10 +9,9 @@ V-axis grid streams the unembedding through VMEM for the running
 hidden tile in place — one pass over the hidden, one launch, and the int8
 wire packet (data + per-row scale) drops out alongside the exit decision.
 
-Grid: (B/block_b, V/block_v) like ``exit_head``; the V axis is minormost
-(sequential on TPU) so VMEM scratch carries the running statistics, and
-the quantized outputs are written once at ``vi == 0`` (their blocks only
-depend on the B index).
+Grid: (B/block_b, V/block_v) like ``exit_head``, whose V-tile step this
+kernel shares; the quantized outputs are written once at ``vi == 0``
+(their blocks only depend on the B index).
 """
 from __future__ import annotations
 
@@ -21,21 +20,16 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
+from repro.kernels.exit_head.kernel import (exit_grid, exit_stats_finish,
+                                            exit_stats_step)
 
 
 def _exit_quant_kernel(h_ref, w_ref, ns_ref, conf_ref, tok_ref, lse_ref,
                        q_ref, s_ref, m_scr, l_scr, a_scr, *, eps: float,
                        block_v: int, n_v: int):
-    vi = pl.program_id(1)
-
-    @pl.when(vi == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        a_scr[...] = jnp.zeros_like(a_scr)
+    @pl.when(pl.program_id(1) == 0)
+    def _quantize():
         # quantize the resident RAW hidden (pre-norm: the wire carries the
         # activation, not the exit-head's normalized view) — same per-row
         # absmax scaling as the transport quantizer
@@ -46,82 +40,31 @@ def _exit_quant_kernel(h_ref, w_ref, ns_ref, conf_ref, tok_ref, lse_ref,
         q_ref[...] = jnp.clip(jnp.round(xf / scale),
                               -127, 127).astype(jnp.int8)
 
-    # rms-norm the hidden block (full d is resident)
-    h = h_ref[...].astype(jnp.float32)                     # (bb, d)
-    var = jnp.mean(jnp.square(h), axis=-1, keepdims=True)
-    ns = ns_ref[...].astype(jnp.float32)
-    hn = h * jax.lax.rsqrt(var + eps) * (1.0 + ns)
-
-    w = w_ref[...].astype(jnp.float32)                     # (bv, d)
-    logits = jax.lax.dot_general(hn, w, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-
-    tile_max = jnp.max(logits, axis=-1)                    # (bb,)
-    tile_arg = (jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                + vi * block_v)
-
-    m_old = m_scr[...]
-    m_new = jnp.maximum(m_old, tile_max)
-    corr = jnp.exp(m_old - m_new)
-    l_scr[...] = (l_scr[...] * corr
-                  + jnp.sum(jnp.exp(logits - m_new[:, None]), axis=-1))
-    a_scr[...] = jnp.where(tile_max > m_old, tile_arg, a_scr[...])
-    m_scr[...] = m_new
-
-    @pl.when(vi == n_v - 1)
-    def _finish():
-        m = m_scr[...]
-        l = l_scr[...]
-        lse = m + jnp.log(l)
-        conf_ref[...] = jnp.exp(m - lse)
-        tok_ref[...] = a_scr[...]
-        lse_ref[...] = lse
+    exit_stats_step(h_ref, w_ref, ns_ref, m_scr, l_scr, a_scr, eps=eps,
+                    block_v=block_v)
+    exit_stats_finish(conf_ref, tok_ref, lse_ref, m_scr, l_scr, a_scr,
+                      n_v=n_v)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("block_b", "block_v", "eps", "interpret"))
 def exit_quant_pallas(hidden: jax.Array, weight: jax.Array,
-                      norm_scale: jax.Array, *, block_b: int = 8,
-                      block_v: int = 512, eps: float = 1e-5,
-                      interpret: bool = True):
+                      norm_scale: jax.Array, *, block_b: int, block_v: int,
+                      eps: float = 1e-5, interpret: bool = False):
     """hidden: (B, d); weight: (V, d) ->
-    (conf (B,), tok (B,), lse (B,), q int8 (B, d), scale fp32 (B, 1))."""
+    (conf (B,), tok (B,), lse (B,), q int8 (B, d), scale fp32 (B, 1)).
+    ``block_b``/``block_v`` must divide B/V (``ops.exit_quant`` picks
+    them)."""
     b, d = hidden.shape
     v = weight.shape[0]
-    block_b = min(block_b, b)
-    block_v = min(block_v, v)
-    assert b % block_b == 0 and v % block_v == 0, (b, v, block_b, block_v)
-    n_b, n_v = b // block_b, v // block_v
-
+    g = exit_grid(b, d, v, block_b, block_v)
+    g["out_specs"] += [pl.BlockSpec((block_b, d), lambda i, j: (i, 0)),
+                       pl.BlockSpec((block_b, 1), lambda i, j: (i, 0))]
+    g["out_shape"] += [jax.ShapeDtypeStruct((b, d), jnp.int8),
+                       jax.ShapeDtypeStruct((b, 1), jnp.float32)]
     kernel = functools.partial(_exit_quant_kernel, eps=eps, block_v=block_v,
-                               n_v=n_v)
+                               n_v=g["grid"][1])
     conf, tok, lse, q, s = pl.pallas_call(
-        kernel,
-        grid=(n_b, n_v),
-        in_specs=[
-            pl.BlockSpec((block_b, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_v, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((d,), lambda i, j: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_b,), lambda i, j: (i,)),
-            pl.BlockSpec((block_b,), lambda i, j: (i,)),
-            pl.BlockSpec((block_b,), lambda i, j: (i,)),
-            pl.BlockSpec((block_b, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_b, 1), lambda i, j: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b,), jnp.float32),
-            jax.ShapeDtypeStruct((b,), jnp.int32),
-            jax.ShapeDtypeStruct((b,), jnp.float32),
-            jax.ShapeDtypeStruct((b, d), jnp.int8),
-            jax.ShapeDtypeStruct((b, 1), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_b,), jnp.float32),
-            pltpu.VMEM((block_b,), jnp.float32),
-            pltpu.VMEM((block_b,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(hidden, weight, norm_scale)
-    return conf, tok, lse, q, s
+        kernel, interpret=interpret, **g,
+    )(hidden, weight, norm_scale.reshape(1, d))
+    return conf[:, 0], tok[:, 0], lse[:, 0], q, s

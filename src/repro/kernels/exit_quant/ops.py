@@ -1,36 +1,24 @@
-"""jit'd public wrapper for the fused exit-head + quantize kernel.
-
-On CPU (this container) the kernel runs in interpret mode; on TPU set
-``interpret=False`` (default resolves from the backend)."""
+"""jit'd public wrapper for the fused exit-head + quantize kernel."""
 from __future__ import annotations
 
 import jax
 
+from repro.kernels.exit_head.ops import exit_blocks
 from repro.kernels.exit_quant.kernel import exit_quant_pallas
 from repro.kernels.exit_quant.ref import exit_quant_ref
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def exit_quant(hidden: jax.Array, weight: jax.Array, norm_scale: jax.Array,
                *, block_b: int = 8, block_v: int = 512, eps: float = 1e-5,
-               interpret: bool = None, use_kernel: bool = True):
+               interpret: bool = False, use_kernel: bool = True):
     """(B,d) hidden + (V,d) unembedding ->
     (confidence, token, logsumexp, q int8 (B,d), scale fp32 (B,1)).
 
     One launch for the below-θ hot path: the exit decision AND the int8
-    wire packet of the same hidden tile.  Falls back to the jnp oracle for
-    shapes the kernel's tiling cannot cover evenly (the oracle IS the
-    reference semantics)."""
-    b, d = hidden.shape
-    v = weight.shape[0]
-    if interpret is None:
-        interpret = _default_interpret()
-    bb = min(block_b, b)
-    bv = min(block_v, v)
-    if not use_kernel or b % bb or v % bv:
+    wire packet of the same hidden tile.  ``use_kernel=False`` runs the
+    jnp oracle (the reference semantics)."""
+    if not use_kernel:
         return exit_quant_ref(hidden, weight, norm_scale, eps)
+    bb, bv = exit_blocks(*hidden.shape, weight.shape[0], block_b, block_v)
     return exit_quant_pallas(hidden, weight, norm_scale, block_b=bb,
                              block_v=bv, eps=eps, interpret=interpret)

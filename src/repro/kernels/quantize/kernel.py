@@ -23,11 +23,12 @@ def _quantize_kernel(x_ref, q_ref, s_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
-def quantize_int8_pallas(x: jax.Array, *, block_n: int = 256,
-                         interpret: bool = True):
+def quantize_int8_pallas(x: jax.Array, *, block_n: int,
+                         interpret: bool = False):
+    """x: (N, d) -> (q int8 (N, d), scale fp32 (N, 1)); ``block_n`` must
+    divide N (``ops.quantize_int8`` picks it)."""
     n, d = x.shape
-    block_n = min(block_n, n)
-    assert n % block_n == 0
+    assert n % block_n == 0, (n, block_n)
     q, s = pl.pallas_call(
         _quantize_kernel,
         grid=(n // block_n,),

@@ -96,10 +96,6 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
             rec["memory_analysis"] = {"error": str(e)}
         try:
             ca = compiled.cost_analysis()
-            # jax <= 0.4.x returns a one-element list of dicts; newer
-            # versions return the dict directly
-            if isinstance(ca, (list, tuple)):
-                ca = ca[0] if ca else {}
             rec["cost_analysis"] = {k: float(v) for k, v in ca.items()
                                     if isinstance(v, (int, float))}
         except Exception as e:

@@ -6,12 +6,21 @@ XLA_FLAGS for 512 host devices *before* any jax import)."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: the serving and dry-run
+    code places arrays with ``NamedSharding`` and pins activations with
+    ``with_sharding_constraint``, which refuses ``Explicit`` axes (the
+    installed JAX's default)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_cloud_mesh(shape):
@@ -31,7 +40,7 @@ def make_cloud_mesh(shape):
             f"cloud_mesh {dims} needs {need} devices but only {have} "
             f"visible; set XLA_FLAGS=--xla_force_host_platform_device_"
             f"count={need} before importing jax to emulate them")
-    return jax.make_mesh(dims, ("data", "model"))
+    return _auto_mesh(dims, ("data", "model"))
 
 
 def make_debug_mesh(n_devices: int = 1):
@@ -42,7 +51,7 @@ def make_debug_mesh(n_devices: int = 1):
         if n % m == 0:
             model = m
             break
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _auto_mesh((n // model, model), ("data", "model"))
 
 
 def pod_submeshes(mesh):

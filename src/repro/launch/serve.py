@@ -19,17 +19,40 @@ import argparse
 import math
 
 import jax
+import jax.numpy as jnp
 
 from repro.configs.registry import get_config, get_smoke_config
-from repro.core.collm import CollmConfig
+from repro.core.collm import CoLLM, CollmConfig
 from repro.core.netsim import NetworkParams
 from repro.core.transport import AsyncSimChannel, CloudServicePoint
 from repro.core.workload import ArrivalProcess, arrival_times
 from repro.data.pipeline import DataConfig, SyntheticCorpus
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.registry import build_model
+from repro.models.transformer import Model
 from repro.serving.adaptive import AdaptiveConfig, ResumeCostModel
 from repro.serving.engine import ServingSystem, token_agreement
+from repro.serving.mesh_exec import mesh_context
 from repro.training.checkpoint import load_checkpoint
+
+
+def served_model(arch: str, smoke: bool) -> Model:
+    """The model as the launcher serves it.  A published config is served
+    in bfloat16 (ee-llm-7b: 13.5 GB of params against 16 GB of v5e HBM;
+    float32 would not fit); smoke configs stay float32."""
+    cfg = get_smoke_config(arch) if smoke else get_config(arch)
+    return build_model(cfg, param_dtype=jnp.float32 if smoke
+                       else jnp.bfloat16)
+
+
+def init_params(model: Model, ccfg: CollmConfig = CollmConfig(),
+                seed: int = 0):
+    """Seeded params from one jitted init, so only the stacked arrays ever
+    reach the device.  With ``ccfg.cloud_mesh`` set they are made straight
+    onto the cloud mesh — never whole on one device first."""
+    sh = mesh_context(CoLLM(model, ccfg)).params_shardings(
+        model.param_specs())
+    return jax.jit(model.init, out_shardings=sh)(jax.random.PRNGKey(seed))
 
 
 def main():
@@ -180,11 +203,9 @@ def main():
                      f"XLA_FLAGS=--xla_force_host_platform_device_count="
                      f"{need} before launching)")
 
-    cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
-    if args.ckpt:
-        params, _ = load_checkpoint(args.ckpt, params)
+    enable_compile_cache()
+    model = served_model(args.arch, args.smoke)
+    cfg = model.cfg
     data = SyntheticCorpus(DataConfig(vocab_size=cfg.vocab_size, seq_len=64,
                                       batch_size=1))
     ccfg = CollmConfig(
@@ -195,6 +216,9 @@ def main():
         chunked_prefill=args.chunked_prefill,
         prefix_share=args.prefix_share,
         cloud_mesh=cloud_mesh)
+    params = init_params(model, ccfg)
+    if args.ckpt:
+        params, _ = load_checkpoint(args.ckpt, params)
     prompts = [data.sample_tokens(args.prompt_len)
                for _ in range(args.clients)]
     if args.prefix_share:

@@ -122,7 +122,6 @@ def _moe_forward_shardmap(params: Params, cfg: ModelConfig, x: jax.Array,
     shard of every expert, and psums the down-projection over ``model``.
     The only collectives are the weight all-gathers GSPMD already inserts
     for FSDP and one psum per layer — no partitioned scatters."""
-    import jax.experimental.shard_map as _shmap
     from jax.sharding import PartitionSpec as P
 
     mesh = policy.mesh
@@ -176,12 +175,12 @@ def _moe_forward_shardmap(params: Params, cfg: ModelConfig, x: jax.Array,
         aux = e * jnp.sum(me * ce) * moe.router_aux_coef
         return out, aux
 
-    fn = _shmap.shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(x_spec, P(), P(None, None, "model"),
                   P(None, None, "model"), P(None, "model", None)),
         out_specs=(x_spec, P()),
-        check_rep=False)
+        check_vma=False)
     out, aux = fn(x, params["router"],
                   params["w_gate"].astype(x.dtype),
                   params["w_up"].astype(x.dtype),

@@ -71,6 +71,14 @@ def _stack(trees: Sequence[Params]) -> Params:
     return jax.tree.map(lambda *xs: jnp.stack(xs, axis=0), *trees)
 
 
+def _init_stacked(rng: jax.Array, n: int, init_one) -> Params:
+    """``n`` layers' params stacked on a leading axis, built by one vmapped
+    init: under ``jax.jit`` only the stacked arrays are materialized (no
+    per-layer copies to stack) and the layer body is traced once.  The
+    values equal stacking ``init_one(r)`` over ``split(rng, n)``."""
+    return jax.vmap(init_one)(jax.random.split(rng, n))
+
+
 class Model:
     """Pure-function model wrapper; all methods take explicit params."""
 
@@ -96,9 +104,9 @@ class Model:
             if seg.shared:
                 seg_params.append({})           # alias of params["shared"]
                 continue
-            layer_rngs = split_rngs(rngs[1 + si], seg.length)
-            seg_params.append(_stack([
-                init_block(r, cfg, seg.kind, dt) for r in layer_rngs]))
+            seg_params.append(_init_stacked(
+                rngs[1 + si], seg.length,
+                lambda r, kind=seg.kind: init_block(r, cfg, kind, dt)))
         params["segments"] = tuple(seg_params)
         if any(s.shared for s in self.segments):
             params["shared"] = init_block(rngs[-6], cfg, SHARED_ATTN, dt,
@@ -115,11 +123,11 @@ class Model:
         params["exit_norms"] = {
             str(l): jnp.zeros((cfg.d_model,), dt) for l in cfg.exit_layers}
         if cfg.is_encdec:
-            enc_rngs = split_rngs(rngs[-4], cfg.encoder_layers)
             params["encoder"] = {
-                "layers": _stack([init_block(r, cfg, DENSE, dt,
-                                             with_cross=False)
-                                  for r in enc_rngs]),
+                "layers": _init_stacked(
+                    rngs[-4], cfg.encoder_layers,
+                    lambda r: init_block(r, cfg, DENSE, dt,
+                                         with_cross=False)),
                 "norm": jnp.zeros((cfg.d_model,), dt)
                 if cfg.norm_type == "rms" else {
                     "scale": jnp.ones((cfg.d_model,), dt),

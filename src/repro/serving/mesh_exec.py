@@ -100,12 +100,19 @@ class MeshContext:
         return self._jitted.get(name)
 
     # -- placement ---------------------------------------------------------
-    def shard_params(self, params: Pytree, *, fsdp: bool = False) -> Pytree:
+    def params_shardings(self, specs: Pytree) -> Optional[Pytree]:
+        """Role-based param shardings on the cloud mesh (None without
+        one); ``specs`` may be shapes, so params can be initialized
+        straight onto the mesh."""
+        if not self.active:
+            return None
+        return shardlib.params_shardings(specs, self.mesh, fsdp=False,
+                                         head_dim=self.head_dim)
+
+    def shard_params(self, params: Pytree) -> Pytree:
         if not self.active:
             return params
-        sh = shardlib.params_shardings(params, self.mesh, fsdp=fsdp,
-                                       head_dim=self.head_dim)
-        return jax.device_put(params, sh)
+        return jax.device_put(params, self.params_shardings(params))
 
     def shard_caches(self, caches: Pytree, *, batch: int) -> Pytree:
         if not self.active:

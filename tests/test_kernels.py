@@ -29,7 +29,8 @@ def test_exit_head_sweep(b, d, v, bb, bv, dtype):
     h = jax.random.normal(rng, (b, d)).astype(dtype)
     w = (jax.random.normal(jax.random.PRNGKey(1), (v, d)) * 0.05).astype(dtype)
     ns = jax.random.normal(jax.random.PRNGKey(2), (d,)) * 0.1
-    c1, t1, l1 = exit_confidence(h, w, ns, block_b=bb, block_v=bv)
+    c1, t1, l1 = exit_confidence(h, w, ns, block_b=bb, block_v=bv,
+                                 interpret=True)
     c2, t2, l2 = exit_head_ref(h, w, ns)
     tol = 1e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(c1), np.asarray(c2), atol=tol)
@@ -43,7 +44,7 @@ def test_exit_head_confidence_bounds():
     rng = jax.random.PRNGKey(3)
     h = jax.random.normal(rng, (8, 64)) * 10
     w = jax.random.normal(jax.random.PRNGKey(4), (512, 64))
-    c, t, l = exit_confidence(h, w, jnp.zeros(64))
+    c, t, l = exit_confidence(h, w, jnp.zeros(64), interpret=True)
     assert bool(jnp.all((c > 0) & (c <= 1.0 + 1e-6)))
     assert bool(jnp.all((t >= 0) & (t < 512)))
 
@@ -66,7 +67,8 @@ def test_decode_attn_sweep(b, h, kv, d, s, bs, fill, window):
     pos = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
     pos = jnp.where(pos < fill, pos, -1)
     cur = jnp.asarray(fill - 1, jnp.int32)
-    o1 = flash_decode(q, k, v, pos, cur, window=window, block_s=bs)
+    o1 = flash_decode(q, k, v, pos, cur, window=window, block_s=bs,
+                      interpret=True)
     o2 = decode_attn_ref(q, k, v, pos, cur, window=window)
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=2e-5)
 
@@ -78,7 +80,8 @@ def test_decode_attn_dtypes(dtype):
     k = jax.random.normal(jax.random.PRNGKey(1), (2, 256, 2, 64)).astype(dtype)
     v = jax.random.normal(jax.random.PRNGKey(2), (2, 256, 2, 64)).astype(dtype)
     pos = jnp.broadcast_to(jnp.arange(256)[None], (2, 256))
-    o1 = flash_decode(q, k, v, pos, jnp.asarray(255), block_s=128)
+    o1 = flash_decode(q, k, v, pos, jnp.asarray(255), block_s=128,
+                      interpret=True)
     o2 = decode_attn_ref(q, k, v, pos, jnp.asarray(255))
     np.testing.assert_allclose(np.asarray(o1, np.float32),
                                np.asarray(o2, np.float32), atol=3e-2)
@@ -236,7 +239,7 @@ def test_decode_attn_paged_int8_property(seed, gaps):
                                     (512, 64, 256)])
 def test_quantize_sweep(n, d, bn):
     x = jax.random.normal(jax.random.PRNGKey(0), (n, d)) * 5
-    qa, sa = quantize_int8(x, block_n=bn)
+    qa, sa = quantize_int8(x, block_n=bn, interpret=True)
     qb, sb = quantize_int8_ref(x)
     assert bool(jnp.all(qa == qb))
     np.testing.assert_allclose(np.asarray(sa), np.asarray(sb), rtol=1e-6)
@@ -248,7 +251,7 @@ def test_quantize_sweep(n, d, bn):
 def test_quantize_roundtrip_property(n, d, scale, seed):
     """Property: int8 roundtrip error bounded by scale/127 per element."""
     x = jax.random.normal(jax.random.PRNGKey(seed), (n, d)) * scale
-    q, s = quantize_int8(x)
+    q, s = quantize_int8(x, interpret=True)
     back = dequantize_int8_ref(q, s)
     bound = np.asarray(s) * 0.5 + 1e-9
     assert np.all(np.abs(np.asarray(back - x)) <= bound + 1e-6)
@@ -263,7 +266,8 @@ def test_exit_head_property(b, v, seed):
     d = 64
     h = jax.random.normal(jax.random.PRNGKey(seed), (b, d))
     w = jax.random.normal(jax.random.PRNGKey(seed + 1), (v, d)) * 0.1
-    c1, t1, _ = exit_confidence(h, w, jnp.zeros(d), block_b=b, block_v=v // 2)
+    c1, t1, _ = exit_confidence(h, w, jnp.zeros(d), block_b=b, block_v=v // 2,
+                                interpret=True)
     c2, t2, _ = exit_head_ref(h, w, jnp.zeros(d))
     np.testing.assert_allclose(np.asarray(c1), np.asarray(c2), atol=1e-5)
     assert bool(jnp.all(t1 == t2))
@@ -310,16 +314,23 @@ def test_exit_quant_ref_is_two_launch_composition():
 
 
 def test_exit_quant_fallback_on_indivisible_shapes():
-    """Shapes the tiling can't cover fall back to the oracle, same outputs."""
-    b, d, v = 5, 48, 300                    # 5 % 4 != 0, 300 % 128 != 0
+    """A vocabulary no 128-multiple tile divides is refused with a clear
+    error, never quietly served by the oracle; ``use_kernel=False`` is the
+    explicit reference.  A B that fits one block is taken whole."""
+    b, d, v = 5, 48, 300                    # 300 % 128 != 0
     h = jax.random.normal(jax.random.PRNGKey(3), (b, d))
     w = jax.random.normal(jax.random.PRNGKey(4), (v, d)) * 0.05
     ns = jnp.zeros((d,))
-    out = exit_quant(h, w, ns, block_b=4, block_v=128, interpret=True)
+    with pytest.raises(ValueError, match="V=300"):
+        exit_quant(h, w, ns, block_b=4, block_v=128, interpret=True)
+    out = exit_quant(h, w, ns, use_kernel=False)
     ref = exit_quant_ref(h, w, ns)
     for a, r in zip(out, ref):
         assert a.shape == r.shape and a.dtype == r.dtype
         assert bool(jnp.all(a == r))
+    ker = exit_quant(h, w[:256], ns, block_b=4, block_v=128, interpret=True)
+    ref = exit_quant_ref(h, w[:256], ns)
+    assert bool(jnp.all(ker[1] == ref[1])) and bool(jnp.all(ker[3] == ref[3]))
 
 
 @settings(max_examples=15, deadline=None)
