@@ -251,9 +251,10 @@ class CoLLM:
         x, exit_h, new_caches, _ = self.model.prefill(
             params, {"tokens": tokens}, caches, self.edge_segs)
         last = jnp.asarray(true_len, jnp.int32) - 1
-        decisions = {l: evaluate_exit(self.model.exit_logits(
-            params, l, jax.lax.dynamic_slice_in_dim(h, last, 1, axis=1)))
-            for l, h in exit_h.items()}
+        with jax.named_scope("exit_heads"):
+            decisions = {l: evaluate_exit(self.model.exit_logits(
+                params, l, jax.lax.dynamic_slice_in_dim(h, last, 1, axis=1)))
+                for l, h in exit_h.items()}
         new_caches = self.model.invalidate_cache_after(new_caches, true_len)
         return decisions, exit_h[self.l_ee1], new_caches
 
@@ -269,8 +270,9 @@ class CoLLM:
             params, h1_seq, ctx, self.cloud_segs, caches=caches,
             collect_exits=False)
         last = jnp.asarray(true_len, jnp.int32) - 1
-        logits = self.model.logits(
-            params, jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1))
+        with jax.named_scope("output_head"):
+            logits = self.model.logits(
+                params, jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1))
         new_caches = self.model.invalidate_cache_after(new_caches, true_len)
         return logits, new_caches
 
@@ -343,11 +345,18 @@ class CoLLM:
                   block_tbl: Optional[jax.Array] = None) -> EdgeStepOut:
         x, exit_h, new_caches = self.model.decode_step(
             params, token, caches, pos, self.edge_segs, block_tbl=block_tbl)
-        decisions = {l: evaluate_exit(self.model.exit_logits(params, l, h))
-                     for l, h in exit_h.items()}
-        tok, exited, _ = first_confident_exit(decisions, self.ccfg.theta)
+        decisions, tok, exited = self._exit_heads(params, exit_h)
         upload = quantize(exit_h[self.l_ee1], self.ccfg.wire_format)
         return EdgeStepOut(decisions, tok, exited, upload, new_caches)
+
+    def _exit_heads(self, params: Params, exit_h: Dict[int, jax.Array]):
+        """Every exit's decision over its hidden state, and the first
+        confident one per row: (decisions, token, exited)."""
+        with jax.named_scope("exit_heads"):
+            decisions = {l: evaluate_exit(self.model.exit_logits(params, l, h))
+                         for l, h in exit_h.items()}
+            tok, exited, _ = first_confident_exit(decisions, self.ccfg.theta)
+        return decisions, tok, exited
 
     def fused_exit_upload(self, params: Params, hidden: jax.Array, *,
                           interpret: bool = False,
@@ -385,7 +394,9 @@ class CoLLM:
         x, _, new_caches = self.model.decode_from_hidden(
             params, hidden, caches, pos, self.cloud_segs,
             block_tbl=block_tbl, write_mask=write_mask)
-        return self.model.logits(params, x)[:, 0], new_caches
+        with jax.named_scope("output_head"):
+            logits = self.model.logits(params, x)[:, 0]
+        return logits, new_caches
 
     def _caches_where_rows(self, mask: jax.Array, new: Dict[int, Pytree],
                            old: Dict[int, Pytree]) -> Dict[int, Pytree]:
@@ -440,9 +451,7 @@ class CoLLM:
         x, exit_h, new_caches = self.model.decode_step(
             params, token, caches, pos, self.edge_segs, block_tbl=block_tbl,
             write_mask=run_mask)
-        decisions = {l: evaluate_exit(self.model.exit_logits(params, l, h))
-                     for l, h in exit_h.items()}
-        tok, exited, _ = first_confident_exit(decisions, self.ccfg.theta)
+        decisions, tok, exited = self._exit_heads(params, exit_h)
         upload = quantize(exit_h[self.l_ee1], self.ccfg.wire_format)
         return EdgeStepOut(decisions, tok, exited, upload,
                            self._caches_where_rows(run_mask, new_caches,

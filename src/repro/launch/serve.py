@@ -289,8 +289,10 @@ def main():
     print(f"tokens={st.tokens} exits@l1={st.exits_l1} exits@l2={st.exits_l2} "
           f"cloud_requests={st.cloud_requests} "
           f"request_rate={st.request_rate:.2%}")
-    print(f"upload={st.upload_bytes/1e3:.1f}KB edge_t={st.edge_time:.2f}s "
-          f"cloud_t={st.cloud_time:.2f}s")
+    print(f"upload={st.upload_bytes/1e3:.1f}KB")
+    print("phases (calls/host s): " + " ".join(
+        f"{name.removeprefix('engine.')}={v['calls']}/{v['seconds']:.3f}"
+        for name, v in r["phases"].items()))
     if args.preemption != "off":
         print(f"preemptions={st.preemptions} policy={args.preempt_policy} "
               f"mode={args.preemption}")

@@ -401,7 +401,8 @@ def attention_forward(params: Params, cfg: ModelConfig, x: jax.Array, *,
 
     new_cache = None
     if cache is not None:
-        new_cache = _cache_write(cache, k, v, kpos, cache_offset)
+        with jax.named_scope("kv_write"):
+            new_cache = _cache_write(cache, k, v, kpos, cache_offset)
     y = jnp.einsum("bse,ed->bsd", out.reshape(b, s, -1),
                    params["wo"].astype(x.dtype))
     return y, new_cache
@@ -441,14 +442,15 @@ def decode_attention(params: Params, cfg: ModelConfig, x: jax.Array,
         if use_rope:
             knew = apply_rope(knew, pos_b[:, None], cfg.rope_theta)
         if update_cache:
-            size = cache["k"].shape[1]
-            slot = (pos_b % size).astype(jnp.int32)
-            bidx = jnp.arange(b)
-            ck = cache["k"].at[bidx, slot].set(
-                knew[:, 0].astype(cache["k"].dtype))
-            cv = cache["v"].at[bidx, slot].set(
-                vnew[:, 0].astype(cache["v"].dtype))
-            cp = cache["pos"].at[bidx, slot].set(pos_b)
+            with jax.named_scope("kv_write"):
+                size = cache["k"].shape[1]
+                slot = (pos_b % size).astype(jnp.int32)
+                bidx = jnp.arange(b)
+                ck = cache["k"].at[bidx, slot].set(
+                    knew[:, 0].astype(cache["k"].dtype))
+                cv = cache["v"].at[bidx, slot].set(
+                    vnew[:, 0].astype(cache["v"].dtype))
+                cp = cache["pos"].at[bidx, slot].set(pos_b)
             cache = {"k": ck, "v": cv, "pos": cp}
         k, v, kpos = cache["k"], cache["v"], cache["pos"]
         new_cache = cache
@@ -469,6 +471,30 @@ def decode_attention(params: Params, cfg: ModelConfig, x: jax.Array,
     out = out.reshape(b, 1, h * hd).astype(x.dtype)
     y = jnp.einsum("bse,ed->bsd", out, params["wo"].astype(x.dtype))
     return y, new_cache
+
+
+def _paged_write(cache: Params, dest: jax.Array, slot: jax.Array,
+                 ok: jax.Array, pos: jax.Array, k: jax.Array,
+                 v: jax.Array) -> Params:
+    """Write new K/V entries (``k``/``v``: ``dest.shape + (KV, d)``) at
+    page ``dest``, slot ``slot``; entries with ``ok`` False carry
+    ``pos = -1`` (the callers point them at the trash page)."""
+    with jax.named_scope("kv_write"):
+        new_cache = {"pos": cache["pos"].at[dest, slot].set(
+            jnp.where(ok, pos, -1))}
+        if "ks" in cache:                          # quantize on write
+            qk, sk = quantize_kv_rows(k)           # int8 entries, scales
+            qv, sv = quantize_kv_rows(v)
+            new_cache["kp"] = cache["kp"].at[dest, slot].set(qk)
+            new_cache["vp"] = cache["vp"].at[dest, slot].set(qv)
+            new_cache["ks"] = cache["ks"].at[dest, slot].set(sk)
+            new_cache["vs"] = cache["vs"].at[dest, slot].set(sv)
+        else:
+            new_cache["kp"] = cache["kp"].at[dest, slot].set(
+                k.astype(cache["kp"].dtype))
+            new_cache["vp"] = cache["vp"].at[dest, slot].set(
+                v.astype(cache["vp"].dtype))
+    return new_cache
 
 
 def decode_attention_paged(params: Params, cfg: ModelConfig, x: jax.Array,
@@ -512,21 +538,8 @@ def decode_attention_paged(params: Params, cfg: ModelConfig, x: jax.Array,
         ok &= write_mask
     dest = jnp.where(ok, page, 0)
     slot = (pos_b % ps).astype(jnp.int32)
-    new_cache = {"pos": cache["pos"].at[dest, slot].set(
-        jnp.where(ok, pos_b, -1))}
-    if "ks" in cache:                              # quantize on write
-        qk, sk = quantize_kv_rows(knew[:, 0])      # (B,KV,d) int8, (B,KV)
-        qv, sv = quantize_kv_rows(vnew[:, 0])
-        new_cache["kp"] = cache["kp"].at[dest, slot].set(qk)
-        new_cache["vp"] = cache["vp"].at[dest, slot].set(qv)
-        new_cache["ks"] = cache["ks"].at[dest, slot].set(sk)
-        new_cache["vs"] = cache["vs"].at[dest, slot].set(sv)
-    else:
-        new_cache["kp"] = cache["kp"].at[dest, slot].set(
-            knew[:, 0].astype(cache["kp"].dtype))
-        new_cache["vp"] = cache["vp"].at[dest, slot].set(
-            vnew[:, 0].astype(cache["vp"].dtype))
-    cache = new_cache
+    cache = _paged_write(cache, dest, slot, ok, pos_b, knew[:, 0],
+                         vnew[:, 0])
 
     k, v, kpos = paged_gather(cache, block_tbl)
     g = h // kvh
@@ -592,21 +605,7 @@ def chunk_attention_paged(params: Params, cfg: ModelConfig, x: jax.Array,
         ok &= wm
     dest = jnp.where(ok, page, 0)
     slot = (pos_bc % ps).astype(jnp.int32)
-    new_cache = {"pos": cache["pos"].at[dest, slot].set(
-        jnp.where(ok, pos_bc, -1))}
-    if "ks" in cache:                              # quantize on write
-        qk, sk = quantize_kv_rows(knew)            # (B,C,KV,d), (B,C,KV)
-        qv, sv = quantize_kv_rows(vnew)
-        new_cache["kp"] = cache["kp"].at[dest, slot].set(qk)
-        new_cache["vp"] = cache["vp"].at[dest, slot].set(qv)
-        new_cache["ks"] = cache["ks"].at[dest, slot].set(sk)
-        new_cache["vs"] = cache["vs"].at[dest, slot].set(sv)
-    else:
-        new_cache["kp"] = cache["kp"].at[dest, slot].set(
-            knew.astype(cache["kp"].dtype))
-        new_cache["vp"] = cache["vp"].at[dest, slot].set(
-            vnew.astype(cache["vp"].dtype))
-    cache = new_cache
+    cache = _paged_write(cache, dest, slot, ok, pos_bc, knew, vnew)
 
     k, v, kpos = paged_gather(cache, block_tbl)
     g = h // kvh

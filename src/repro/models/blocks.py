@@ -175,13 +175,14 @@ def block_forward(params: Params, cfg: ModelConfig, kind: str, x: jax.Array,
     """Returns (x, aux_loss, new_cache)."""
     aux = jnp.zeros((), jnp.float32)
     if kind in (DENSE, SHARED_ATTN, MOE):
-        h = _norm(x, params, cfg, "ln1")
         self_cache = cache.get("self") if cache else None
-        att, new_self = attention_forward(
-            params["attn"], cfg, h, positions=ctx.positions,
-            causal=ctx.causal, window=ctx.window, prefix_len=ctx.prefix_len,
-            use_rope=cfg.use_rope, cache=self_cache,
-            cache_offset=ctx.cache_offset)
+        with jax.named_scope("attention"):
+            h = _norm(x, params, cfg, "ln1")
+            att, new_self = attention_forward(
+                params["attn"], cfg, h, positions=ctx.positions,
+                causal=ctx.causal, window=ctx.window,
+                prefix_len=ctx.prefix_len, use_rope=cfg.use_rope,
+                cache=self_cache, cache_offset=ctx.cache_offset)
         # mid-block sequence-parallel point (active ShardingPolicy only):
         # the residual re-enters its (batch, "model", None) layout between
         # the attention and MLP sub-layers instead of drifting to whatever
@@ -202,11 +203,12 @@ def block_forward(params: Params, cfg: ModelConfig, kind: str, x: jax.Array,
                 new_cache["cross"] = build_cross_cache(
                     params["cross"], cfg, ctx.enc_out,
                     dtype=cache["cross"]["k"].dtype)
-        h2 = _norm(x, params, cfg, "ln2")
-        if kind == MOE:
-            y, aux = moe_mod.moe_forward(params["moe"], cfg, h2)
-        else:
-            y = _mlp(params["mlp"], cfg, h2)
+        with jax.named_scope("mlp"):
+            h2 = _norm(x, params, cfg, "ln2")
+            if kind == MOE:
+                y, aux = moe_mod.moe_forward(params["moe"], cfg, h2)
+            else:
+                y = _mlp(params["mlp"], cfg, h2)
         return x + y, aux, new_cache
     if kind == MLSTM:
         if cache is not None:
@@ -238,22 +240,23 @@ def block_decode(params: Params, cfg: ModelConfig, kind: str, x: jax.Array,
     (``x``: (B,C,d) with ``ctx.pos`` the chunk's first position and
     ``ctx.write_mask`` optionally (B,C)) — the chunked-prefill path."""
     if kind in (DENSE, SHARED_ATTN, MOE):
-        h = _norm(x, params, cfg, "ln1")
-        if "kp" in cache["self"] and x.shape[1] > 1:
-            att, new_self = chunk_attention_paged(
-                params["attn"], cfg, h, cache["self"], ctx.pos,
-                ctx.block_tbl, window=ctx.window, use_rope=cfg.use_rope,
-                write_mask=ctx.write_mask)
-        elif "kp" in cache["self"]:
-            att, new_self = decode_attention_paged(
-                params["attn"], cfg, h, cache["self"], ctx.pos,
-                ctx.block_tbl, window=ctx.window, use_rope=cfg.use_rope,
-                write_mask=ctx.write_mask)
-        else:
-            att, new_self = decode_attention(params["attn"], cfg, h,
-                                             cache["self"], ctx.pos,
-                                             window=ctx.window,
-                                             use_rope=cfg.use_rope)
+        with jax.named_scope("attention"):
+            h = _norm(x, params, cfg, "ln1")
+            if "kp" in cache["self"] and x.shape[1] > 1:
+                att, new_self = chunk_attention_paged(
+                    params["attn"], cfg, h, cache["self"], ctx.pos,
+                    ctx.block_tbl, window=ctx.window, use_rope=cfg.use_rope,
+                    write_mask=ctx.write_mask)
+            elif "kp" in cache["self"]:
+                att, new_self = decode_attention_paged(
+                    params["attn"], cfg, h, cache["self"], ctx.pos,
+                    ctx.block_tbl, window=ctx.window, use_rope=cfg.use_rope,
+                    write_mask=ctx.write_mask)
+            else:
+                att, new_self = decode_attention(params["attn"], cfg, h,
+                                                 cache["self"], ctx.pos,
+                                                 window=ctx.window,
+                                                 use_rope=cfg.use_rope)
         # same mid-block sequence-parallel point as block_forward (no-op
         # for S=1 decode; load-bearing for page-sized prefill chunks)
         x = constrain_residual(x + att)
@@ -265,11 +268,12 @@ def block_decode(params: Params, cfg: ModelConfig, kind: str, x: jax.Array,
                                        cache["cross"], ctx.pos, cross=True,
                                        use_rope=False)
             x = x + catt
-        h2 = _norm(x, params, cfg, "ln2")
-        if kind == MOE:
-            y = moe_mod.moe_forward_decode(params["moe"], cfg, h2)
-        else:
-            y = _mlp(params["mlp"], cfg, h2)
+        with jax.named_scope("mlp"):
+            h2 = _norm(x, params, cfg, "ln2")
+            if kind == MOE:
+                y = moe_mod.moe_forward_decode(params["moe"], cfg, h2)
+            else:
+                y = _mlp(params["mlp"], cfg, h2)
         return x + y, new_cache
     if kind == MLSTM:
         return xlstm.mlstm_decode(params, cfg, x, cache)

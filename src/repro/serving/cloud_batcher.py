@@ -37,7 +37,6 @@ them.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, Dict, List, Optional
 
 import jax
@@ -288,10 +287,6 @@ class BatcherStats:
     prefix_hit_tokens: int = 0  # prompt tokens served from shared pages
     restores: int = 0           # preempted-stream cloud-KV replays
     swaps: int = 0              # cloud rows swapped out to host
-    # host seconds spent in batched wave compute.  Prefill time is NOT
-    # included: the admitting engine times admit() and charges it to the
-    # admitting stream's GenStats, so summing the two never double-counts.
-    cloud_time: float = 0.0
 
     @property
     def mean_batch(self) -> float:
@@ -304,8 +299,7 @@ class BatcherStats:
                 "cancelled": self.cancelled, "prefills": self.prefills,
                 "prefill_chunks": self.prefill_chunks,
                 "prefix_hit_tokens": self.prefix_hit_tokens,
-                "restores": self.restores, "swaps": self.swaps,
-                "cloud_time_s": round(self.cloud_time, 4)}
+                "restores": self.restores, "swaps": self.swaps}
 
 
 class CloudBatcher:
@@ -638,13 +632,11 @@ class CloudBatcher:
                 lp = p // self.pool.page_size
                 if self.pool.block_table[slot, lp] == -1:
                     self._alloc(slot, lp)
-        t0 = time.perf_counter()
         ring, ring_pos, valid = build_upload_ring([(slot, packets)], self.B)
         _, self.caches = self._ring_cloud(
             self.params, ring, ring_pos, valid, self.caches,
             self._block_tbl())
         self.stats.restores += 1
-        self.stats.cloud_time += time.perf_counter() - t0
 
     def swap_out(self, device_id: str):
         """Preempt (swap mode): snapshot the stream's cloud-KV pages to
@@ -714,7 +706,6 @@ class CloudBatcher:
         return self._tbl_device
 
     def _compute(self, wave: List[_Entry]) -> None:
-        t0 = time.perf_counter()
         # any multi-packet entry (backfill ring OR k-token draft) needs the
         # ring pass; an all-singles wave takes the dense masked step
         ring_mode = any(len(e.packets) > 1 for e in wave)
@@ -752,7 +743,6 @@ class CloudBatcher:
         self.stats.steps += 1
         self.stats.rows += len(wave)
         self.stats.max_rows = max(self.stats.max_rows, len(wave))
-        self.stats.cloud_time += time.perf_counter() - t0
 
     def kv_cache_bytes(self) -> int:
         return sum(l.size * l.dtype.itemsize

@@ -46,16 +46,16 @@ standalone mode (the paper's unstable-link fallback).  The default
 docs/async_transport.md.
 
 Everything is measured: per-token exit level, cloud request rate, wire
-bytes, deadline misses, virtual stall/overlap time, partition wall-times
-(feeds the netsim), and agreement vs. the undivided model (the paper's
-ROUGE-L proxy).
+bytes, deadline misses, virtual stall/overlap time, agreement vs. the
+undivided model (the paper's ROUGE-L proxy), and the host time of each
+phase of the batched engine (``BatchScheduler.phases``: one profiler span
+and one counter per phase, docs/serving.md §Tracing).
 """
 from __future__ import annotations
 
 import collections
 import dataclasses
 import math
-import time
 from typing import Any, Dict, List, Optional, Sequence
 
 import jax
@@ -82,6 +82,7 @@ from repro.serving.cloud_batcher import (COPY_PAGES, RESET_PAGES, SCATTER,
                                          gather_slot_pages,
                                          rebind_slot_pages)
 from repro.serving.mesh_exec import mesh_context
+from repro.serving.phases import Phases, total as total_phases
 
 Pytree = Any
 
@@ -110,8 +111,6 @@ class GenStats:
     cow_copies: int = 0
     prefill_chunks: int = 0
     upload_bytes: int = 0
-    edge_time: float = 0.0
-    cloud_time: float = 0.0
     stall_s: float = 0.0          # virtual time stalled on in-flight replies
     overlap_s: float = 0.0        # virtual flight time hidden behind decode
     confidences: List[tuple] = dataclasses.field(default_factory=list)
@@ -513,6 +512,9 @@ class BatchScheduler:
         self.fallback_after = int(fallback_after)
         self.vnow = 0.0
         self.last_virtual_time = 0.0
+        # host calls and seconds per phase of admit/tick, each phase also a
+        # profiler span ``engine.<phase>`` (docs/serving.md §Tracing)
+        self.phases = Phases()
         self.late_drops = 0          # replies dropped after slot moved on
         self._idle_s = 0.0           # virtual time nobody decoded (waits)
         self._spec = bool(self.ccfg.speculative) and mode == "collm"
@@ -857,6 +859,10 @@ class BatchScheduler:
         return self._scatter_paged(full, row, slot.index, jnp.asarray(pages))
 
     def _admit(self, queue) -> bool:
+        with self.phases.phase("admit"):
+            return self._admit_queue(queue)
+
+    def _admit_queue(self, queue) -> bool:
         # preempted streams resume first (they hold finished work and the
         # head-of-line must not starve behind fresh admissions); while any
         # still waits for pages, new requests stay queued
@@ -911,50 +917,10 @@ class BatchScheduler:
                 if slot.prefill_prompt is None:   # terminal fast path
                     self._maybe_finish(slot)
                 continue
-            pages = (self._admit_pages(slot, p_len, pad)
-                     if self.pool is not None else None)
-            tokens = np.zeros((1, pad), np.int32)
-            tokens[0, :p_len] = prompt
             st = GenStats()
-            if self.mode == "cloud":
-                t0 = time.perf_counter()
-                logits, row = self._full_prefill(self.params, tokens, p_len,
-                                                 self._full_row0)
-                self.main_caches = self._scatter_admit(self.main_caches, row,
-                                                       slot, pages)
-                first = self._pick(np.asarray(logits)[:, 0])
-                st.cloud_time += time.perf_counter() - t0
-                tok = int(first[0])
-            else:
-                t0 = time.perf_counter()
-                decisions, h1_seq, row = self._edge_prefill(
-                    self.params, tokens, p_len, self._edge_row0)
-                self.edge_caches = self._scatter_admit(self.edge_caches, row,
-                                                       slot, pages)
-                fetched = jax.device_get(
-                    {l: (d.token, d.confidence, d.logits)
-                     for l, d in decisions.items()})
-                st.edge_time += time.perf_counter() - t0
-
-                prefill_logits = None
-                if self.mode == "collm":
-                    t0 = time.perf_counter()
-                    if self._batcher is not None:
-                        logits = self._batcher.admit(
-                            req.device_id, h1_seq, p_len,
-                            p_len + req.max_new)
-                    else:
-                        logits, crow = self._cloud_prefill(
-                            self.params, h1_seq, p_len, self._cloud_row0)
-                        self.cloud_caches = self._scatter_admit(
-                            self.cloud_caches, crow, slot, pages)
-                    prefill_logits = np.asarray(logits)[:, 0]
-                    st.cloud_time += time.perf_counter() - t0
-                    st.upload_bytes += hidden_wire_bytes(
-                        self.model.cfg.d_model, self.ccfg.wire_format,
-                        seq=p_len)
-
-                tok = self._first_token(fetched, prefill_logits, st)
+            with self.phases.phase("prefill", request=req.index,
+                                   tokens=p_len):
+                tok = self._prefill(slot, req, prompt, p_len, pad, st)
             st.tokens = 1
             slot.req, slot.stats = req, st
             slot.tokens = [tok]
@@ -973,6 +939,47 @@ class BatchScheduler:
             admitted = True
             self._maybe_finish(slot)
         return admitted
+
+    def _prefill(self, slot: _Slot, req: Request, prompt: np.ndarray,
+                 p_len: int, pad: int, st: GenStats) -> int:
+        """Monolithic admission: prefill the padded prompt through the
+        partitions the mode runs, scatter the rows into ``slot``'s caches
+        and return the first token."""
+        pages = (self._admit_pages(slot, p_len, pad)
+                 if self.pool is not None else None)
+        tokens = np.zeros((1, pad), np.int32)
+        tokens[0, :p_len] = prompt
+        if self.mode == "cloud":
+            logits, row = self._full_prefill(self.params, tokens, p_len,
+                                             self._full_row0)
+            self.main_caches = self._scatter_admit(self.main_caches, row,
+                                                   slot, pages)
+            with self.phases.phase("prefill_fetch"):
+                logits = np.asarray(logits)
+            return int(self._pick(logits[:, 0])[0])
+        decisions, h1_seq, row = self._edge_prefill(
+            self.params, tokens, p_len, self._edge_row0)
+        self.edge_caches = self._scatter_admit(self.edge_caches, row,
+                                               slot, pages)
+        with self.phases.phase("prefill_fetch"):
+            fetched = jax.device_get(
+                {l: (d.token, d.confidence, d.logits)
+                 for l, d in decisions.items()})
+        prefill_logits = None
+        if self.mode == "collm":
+            if self._batcher is not None:
+                logits = self._batcher.admit(
+                    req.device_id, h1_seq, p_len, p_len + req.max_new)
+            else:
+                logits, crow = self._cloud_prefill(
+                    self.params, h1_seq, p_len, self._cloud_row0)
+                self.cloud_caches = self._scatter_admit(
+                    self.cloud_caches, crow, slot, pages)
+            with self.phases.phase("prefill_fetch"):
+                prefill_logits = np.asarray(logits)[:, 0]
+            st.upload_bytes += hidden_wire_bytes(
+                self.model.cfg.d_model, self.ccfg.wire_format, seq=p_len)
+        return self._first_token(fetched, prefill_logits, st)
 
     def _admit_chunked(self, slot: _Slot, req: Request, prompt: np.ndarray,
                        p_len: int, st: GenStats, hit, hit_pages: int,
@@ -1077,16 +1084,13 @@ class BatchScheduler:
         chunk = np.zeros((1, ps), np.int32)
         chunk[0, :clen] = prompt[pos0:pos0 + clen]
         row_tbl = jnp.asarray(pool.block_table[s.index:s.index + 1])
-        t0 = time.perf_counter()
         decisions, h1, self.edge_caches = self._edge_chunk(
             self.params, jnp.asarray(chunk), jnp.asarray(pos0, jnp.int32),
             clen, self.edge_caches, row_tbl)
-        st.edge_time += time.perf_counter() - t0
         st.prefill_chunks += 1
         final = pos0 + clen >= p_len
         prefill_logits = None
         if self.mode == "collm":
-            t0 = time.perf_counter()
             if self._batcher is not None:
                 logits = self._batcher.admit_chunk(req.device_id, h1,
                                                    pos0, clen)
@@ -1094,7 +1098,6 @@ class BatchScheduler:
                 logits, self.cloud_caches = self._cloud_chunk(
                     self.params, h1, jnp.asarray(pos0, jnp.int32), clen,
                     self.cloud_caches, row_tbl)
-            st.cloud_time += time.perf_counter() - t0
             # only the not-shared suffix crosses the wire, chunk by chunk
             # (true chunk length, not the padded page — byte-identical in
             # sum to the monolithic upload of the same suffix)
@@ -1493,7 +1496,6 @@ class BatchScheduler:
         consumed-upload log replays the cloud KV — gaps at early-exited
         positions included, exactly as the un-preempted run left them."""
         p_len = len(prompt)
-        st = ck.stats
         pad = self._resume_pad(resume_pos)
         tokens = np.zeros((1, pad), np.int32)
         tokens[0, :p_len] = prompt
@@ -1501,25 +1503,20 @@ class BatchScheduler:
         pages = (self._admit_pages(slot, resume_pos, pad)
                  if self.pool is not None else None)
         if self.mode == "cloud":
-            t0 = time.perf_counter()
             _, row = self._full_prefill(self.params, tokens, resume_pos,
                                         self._full_row0)
             self.main_caches = self._scatter_admit(self.main_caches, row,
                                                    slot, pages)
-            st.cloud_time += time.perf_counter() - t0
             return
-        t0 = time.perf_counter()
         _, h1_seq, row = self._edge_prefill(self.params, tokens, resume_pos,
                                             self._edge_row0)
         self.edge_caches = self._scatter_admit(self.edge_caches, row, slot,
                                                pages)
-        st.edge_time += time.perf_counter() - t0
         if self.mode != "collm":
             return
         # cloud prompt prefill (same padded hidden slice as admission) +
         # replay of the consumed decode uploads; the re-prefill h1 is NOT
         # re-uploaded — the wire already carried it before preemption
-        t0 = time.perf_counter()
         pad_p = (min(_bucket(p_len), self._row_seq) if self._pad_ok
                  else p_len)
         h1_p = h1_seq[:, :pad_p]
@@ -1540,7 +1537,6 @@ class BatchScheduler:
             self.cloud_caches = self._scatter_admit(self.cloud_caches, crow,
                                                     slot, cpages)
             self._replay_cloud(slot, ck.cloud_pkts)
-        st.cloud_time += time.perf_counter() - t0
 
     def _replay_cloud(self, slot: _Slot, pkts: List[tuple]) -> None:
         """Own-cloud replay of the checkpointed consumed uploads (one
@@ -1562,6 +1558,11 @@ class BatchScheduler:
         the same tick).  When every active row is blocked on the channel,
         the virtual clock jumps to the next arrival/deadline instead of
         busy-waiting."""
+        with self.phases.phase("tick",
+                               rows=sum(s.active for s in self.slots)):
+            self._tick()
+
+    def _tick(self) -> None:
         self._tick_no += 1
         if self._adaptive is not None:
             self._adaptive.on_tick(self._tick_no, self.pool,
@@ -1589,6 +1590,25 @@ class BatchScheduler:
                 self._advance_idle()
                 self._resolve()
             return
+        if self.mode == "cloud":
+            runnable, tokens, pos = self._start_decode(runnable)
+            self._tick_cloud(runnable, tokens, pos)
+        else:
+            with self.phases.phase("edge_launch", rows=len(runnable)):
+                runnable, tokens, pos = self._start_decode(runnable)
+                out = self._launch_edge(runnable, tokens, pos)
+            self._tick_edge(runnable, out, pos)
+
+        for s in runnable:
+            s.pos += 1
+            self._maybe_finish(s)
+        self._resolve()
+
+    def _start_decode(self, runnable: List[_Slot]):
+        """Give every runnable row a page for this tick's KV write, lay out
+        the step's tokens and positions, and advance the virtual clock by
+        the tick's compute.  Returns the rows still runnable (a page
+        shortage may have preempted some) with the two arrays."""
         for s in runnable:
             if self.pool is not None and s.active:
                 # alloc-on-write: this tick writes KV at s.pos; an empty
@@ -1608,20 +1628,10 @@ class BatchScheduler:
             if s.active:     # stalled rows: placeholder decode, outputs dropped
                 tokens[s.index, 0] = s.last_token
                 pos[s.index] = s.pos
-
         self.vnow += self.tick_time_s    # this tick's edge compute (virtual)
-        if self.mode == "cloud":
-            self._tick_cloud(runnable, tokens, pos)
-        else:
-            self._tick_edge(runnable, tokens, pos)
-
-        for s in runnable:
-            s.pos += 1
-            self._maybe_finish(s)
-        self._resolve()
+        return runnable, tokens, pos
 
     def _tick_cloud(self, runnable, tokens, pos) -> None:
-        t0 = time.perf_counter()
         tok, logits, self.main_caches = self._full_step(
             self.params, jnp.asarray(tokens), self.main_caches,
             jnp.asarray(pos), self._block_tbl())
@@ -1629,14 +1639,12 @@ class BatchScheduler:
             next_tok = np.asarray(tok)
         else:
             next_tok = self._pick(np.asarray(logits))
-        dt = (time.perf_counter() - t0) / len(runnable)
         for s in runnable:
-            s.stats.cloud_time += dt
             self._emit(s, int(next_tok[s.index]), "full")
 
-    def _tick_edge(self, runnable, tokens, pos) -> None:
-        collm, ccfg = self.collm, self.ccfg
-        t0 = time.perf_counter()
+    def _launch_edge(self, runnable, tokens, pos):
+        """Dispatch the batched edge step (asynchronous: returns its
+        still-on-device outputs)."""
         jt, jp, tbl = jnp.asarray(tokens), jnp.asarray(pos), self._block_tbl()
         if self._mask_edge:
             run_mask = np.zeros((self.B,), bool)
@@ -1647,6 +1655,10 @@ class BatchScheduler:
         else:
             out = self._edge_step(self.params, jt, self.edge_caches, jp, tbl)
         self.edge_caches = out.caches
+        return out
+
+    def _tick_edge(self, runnable, out, pos) -> None:
+        collm, ccfg = self.collm, self.ccfg
         want_logits = self.sampler != "greedy"
         get = {
             "token": out.token, "exited": out.exited,
@@ -1663,13 +1675,12 @@ class BatchScheduler:
                 # also what a standalone-fallback row samples from
                 get["sel_logits"] = select_exit_logits(
                     out.decisions, ccfg.theta)[0]
-        fetched = jax.device_get(get)
-        edge_dt = (time.perf_counter() - t0) / len(runnable)
+        with self.phases.phase("edge_fetch", rows=len(runnable)):
+            fetched = jax.device_get(get)
         exited = fetched["exited"]
         confs = fetched["conf"]
 
         for s in runnable:
-            s.stats.edge_time += edge_dt
             s.stats.tokens += 1
             c1 = float(confs.get(collm.l_ee1, np.zeros(self.B))[s.index])
             c2 = float(confs.get(collm.l_ee2, np.zeros(self.B))[s.index])
@@ -1692,15 +1703,16 @@ class BatchScheduler:
         # Standalone-fallback rows have given up on the cloud: no upload.
         up = fetched["upload"]
         uploaders = [s for s in runnable if not s.standalone]
-        pkts = {s.index: StatePacket(
-            hidden={k: v[s.index:s.index + 1] for k, v in up.items()},
-            pos=s.pos) for s in uploaders}
-        self.cm.upload_batch((s.req.device_id, s.pos, pkts[s.index])
-                             for s in uploaders)
-        for s in uploaders:
-            nb = pkts[s.index].nbytes()
-            s.stats.upload_bytes += nb
-            self.channel.notify_upload(s.index, nb, self.vnow)
+        with self.phases.phase("upload", rows=len(uploaders)):
+            pkts = {s.index: StatePacket(
+                hidden={k: v[s.index:s.index + 1] for k, v in up.items()},
+                pos=s.pos) for s in uploaders}
+            self.cm.upload_batch((s.req.device_id, s.pos, pkts[s.index])
+                                 for s in uploaders)
+            for s in uploaders:
+                nb = pkts[s.index].nbytes()
+                s.stats.upload_bytes += nb
+                self.channel.notify_upload(s.index, nb, self.vnow)
 
         exit_toks = (fetched["token"] if self.sampler == "greedy"
                      else self._pick(fetched["sel_logits"]))
@@ -1717,7 +1729,8 @@ class BatchScheduler:
             # in k-sized verification requests
             self._draft_tick(needy, uploaders, prov_toks)
         elif needy:
-            self._dispatch_cloud(needy, pos, prov_toks)
+            with self.phases.phase("cloud_launch", rows=len(needy)):
+                self._dispatch_cloud(needy, pos, prov_toks)
         for s in runnable:
             if bool(exited[s.index]):
                 if s.stats.confidences[-1][0] >= ccfg.theta:
@@ -1756,7 +1769,6 @@ class BatchScheduler:
         # flushes before its snapshot), so tracking there would only
         # hoard host memory
         track = self.preemption == "recompute"
-        t0 = time.perf_counter()
         if self._batcher is not None:
             # shared cloud: queue per-row requests with the CloudBatcher —
             # it coalesces them with OTHER engines' concurrent requests
@@ -1801,10 +1813,8 @@ class BatchScheduler:
             group = {"logits": logits, "np": None}   # materialized at drain
             payloads = {s.index: (group, s.index) for s in needy}
 
-        dt = (time.perf_counter() - t0) / len(needy)
         handles = []
         for s in needy:
-            s.stats.cloud_time += dt
             h = self.channel.submit(
                 slot=s.index, seq=s.seq, pos=s.pos,
                 reply=payloads[s.index], now=self.vnow,
@@ -1875,7 +1885,6 @@ class BatchScheduler:
         classic speculative path."""
         ccfg = self.ccfg
         track = self.preemption == "recompute"
-        t0 = time.perf_counter()
         ring_maps: Dict[int, Dict[int, int]] = {}
         if self._batcher is not None:
             payloads = {}
@@ -1936,10 +1945,8 @@ class BatchScheduler:
                          "np": None, "np_all": None}
             payloads = {s.index: (group, s.index) for s in rows}
 
-        dt = (time.perf_counter() - t0) / len(rows)
         handles = []
         for s in rows:
-            s.stats.cloud_time += dt
             kk = len(s.draft)
             rm = ring_maps[s.index]
             for d in s.draft:
@@ -1993,12 +2000,13 @@ class BatchScheduler:
         and return this row's token."""
         group, row = rep.reply
         if group["np"] is None:
-            if group["logits"] is None:
-                # CloudBatcher reply: the batched cloud step is lazy so
-                # that concurrent engines' requests land in one wave —
-                # first materialization computes it
-                group["flush"]()
-            logits = np.asarray(group["logits"])
+            with self.phases.phase("cloud_fetch"):
+                if group["logits"] is None:
+                    # CloudBatcher reply: the batched cloud step is lazy so
+                    # that concurrent engines' requests land in one wave —
+                    # first materialization computes it
+                    group["flush"]()
+                logits = np.asarray(group["logits"])
             if self.sampler == "greedy":
                 group["np"] = np.argmax(logits, axis=-1)
             else:
@@ -2053,62 +2061,63 @@ class BatchScheduler:
     def _resolve(self) -> None:
         """Drain arrived replies, then expire deadlines, at the current
         virtual time."""
-        for rep in self.channel.poll(self.vnow):
-            s = self.slots[rep.slot] if rep.slot < self.B else None
-            if (s is None or not s.active or s.seq != rep.seq
-                    or rep.handle not in s.pending):
-                # the slot retired, was refilled, or rewound past this
-                # position: a late reply must never land on its successor
-                self.late_drops += 1
-                continue
-            pend = s.pending.pop(rep.handle)
-            if rep.arrival_t > pend.deadline_t:
-                # arrival and deadline crossed within one clock advance:
-                # the deadline fired first — the reply is late even though
-                # we only see both now
-                self._deadline_miss(s, pend)
-                self.late_drops += 1
-                self._maybe_finish(s)
-                continue
-            if self._spec:
-                s.stats.overlap_s += self._hidden_s(pend)
-                s.miss_streak = 0
-                toks = self._draft_tokens(rep)
-                accepted = 0
-                for d in pend.draft:
-                    cloud_tok = int(toks[d.ring_idx])
-                    if cloud_tok == s.tokens[d.tok_index]:
-                        # validated: the provisional token IS the cloud
-                        # token
-                        s.events[d.tok_index] = "cloud"
-                        s.stats.cloud_requests += 1
-                        s.stats.accepted_tokens += 1
-                        accepted += 1
-                    else:
-                        # first disagreement: correct it and discard the
-                        # rejected suffix (later positions' scores were
-                        # conditioned on a wrong token)
-                        self._rewind(s, d, cloud_tok)
-                        break
-                s.stats.accept_lens.append(accepted)
-            else:
-                tok = self._reply_token(rep)
-                s.stats.cloud_requests += 1
-                s.stats.stall_s += self.vnow - pend.stall_from
-                s.stats.overlap_s += self._hidden_s(pend)
-                s.miss_streak = 0
-                self._emit(s, tok, "cloud")
-            self._maybe_finish(s)
-        # latency-aware early exit: overdue replies commit the edge token
-        for s in self.slots:
-            if not s.active or not s.pending:
-                continue
-            for h, pend in list(s.pending.items()):
-                if pend.deadline_t > self.vnow:
+        with self.phases.phase("resolve"):
+            for rep in self.channel.poll(self.vnow):
+                s = self.slots[rep.slot] if rep.slot < self.B else None
+                if (s is None or not s.active or s.seq != rep.seq
+                        or rep.handle not in s.pending):
+                    # the slot retired, was refilled, or rewound past this
+                    # position: a late reply must never land on its successor
+                    self.late_drops += 1
                     continue
-                del s.pending[h]
-                self._deadline_miss(s, pend)
+                pend = s.pending.pop(rep.handle)
+                if rep.arrival_t > pend.deadline_t:
+                    # arrival and deadline crossed within one clock
+                    # advance: the deadline fired first — the reply is late
+                    # even though we only see both now
+                    self._deadline_miss(s, pend)
+                    self.late_drops += 1
+                    self._maybe_finish(s)
+                    continue
+                if self._spec:
+                    s.stats.overlap_s += self._hidden_s(pend)
+                    s.miss_streak = 0
+                    toks = self._draft_tokens(rep)
+                    accepted = 0
+                    for d in pend.draft:
+                        cloud_tok = int(toks[d.ring_idx])
+                        if cloud_tok == s.tokens[d.tok_index]:
+                            # validated: the provisional token IS the cloud
+                            # token
+                            s.events[d.tok_index] = "cloud"
+                            s.stats.cloud_requests += 1
+                            s.stats.accepted_tokens += 1
+                            accepted += 1
+                        else:
+                            # first disagreement: correct it and discard the
+                            # rejected suffix (later positions' scores were
+                            # conditioned on a wrong token)
+                            self._rewind(s, d, cloud_tok)
+                            break
+                    s.stats.accept_lens.append(accepted)
+                else:
+                    tok = self._reply_token(rep)
+                    s.stats.cloud_requests += 1
+                    s.stats.stall_s += self.vnow - pend.stall_from
+                    s.stats.overlap_s += self._hidden_s(pend)
+                    s.miss_streak = 0
+                    self._emit(s, tok, "cloud")
                 self._maybe_finish(s)
+            # latency-aware early exit: overdue replies commit the edge token
+            for s in self.slots:
+                if not s.active or not s.pending:
+                    continue
+                for h, pend in list(s.pending.items()):
+                    if pend.deadline_t > self.vnow:
+                        continue
+                    del s.pending[h]
+                    self._deadline_miss(s, pend)
+                    self._maybe_finish(s)
 
     def _advance_idle(self) -> None:
         """Every active row is blocked on the channel: jump the virtual
@@ -2378,7 +2387,8 @@ class ServingSystem:
         to a blocking drain, and ``fallback_after`` N consecutive deadline
         misses flips a stream to standalone mode.  The result dict gains
         ``virtual_time`` (this run's virtual makespan), ``late_drops``,
-        and ``channel_stats``.
+        ``channel_stats`` and ``phases`` (this run's host calls and seconds
+        per engine phase, ``BatchScheduler.phases``).
 
         Under ``CollmConfig.preemption != "off"`` the paged pool admits
         optimistically and preempts victims when pages run dry;
@@ -2432,10 +2442,11 @@ class ServingSystem:
                                    if arrivals is not None else 0.0),
                         slo_ttft_s=slo_ttft_s, slo_tpot_s=slo_tpot_s)
                 for i, p in enumerate(prompts)]
+        sched.phases.reset()
         results, stats = sched.run(reqs)
         return {"tokens": results, "stats": _aggregate(stats),
                 "per_client": stats, "cm_stats": self.cloud.cm.stats(),
-                "num_slots": slots,
+                "num_slots": slots, "phases": sched.phases.snapshot(),
                 "virtual_time": sched.last_virtual_time,
                 "late_drops": sched.late_drops,
                 "channel_stats": sched.channel.stats.as_row(),
@@ -2475,8 +2486,9 @@ class ServingSystem:
         requests contend in (FIFO) or coalesce at (batched) the same
         virtual cloud queue.  Defaults to a ``SyncChannel`` each, in which
         case the streams are token-identical to independent
-        ``generate()`` runs.  Returns the usual result dict plus
-        ``n_engines`` and, in cloud-batch mode, the batcher's stats row.
+        ``generate()`` runs.  Returns the usual result dict (``phases``
+        summed over the engines) plus ``n_engines`` and, in cloud-batch
+        mode, the batcher's stats row.
 
         ``arrivals`` / ``slo_ttft_s`` / ``slo_tpot_s`` mirror
         ``generate()``: open-loop fleet replay stamps one virtual arrival
@@ -2530,14 +2542,11 @@ class ServingSystem:
                "per_client": flat, "cm_stats": self.cloud.cm.stats(),
                "n_engines": n, "virtual_time": makespan,
                "late_drops": sum(s.late_drops for s in scheds),
-               "channel_stats": ch_agg.as_row()}
+               "channel_stats": ch_agg.as_row(),
+               # summed over the engines; a CloudBatcher wave runs inside
+               # the engine whose reply drain first needs it (cloud_fetch)
+               "phases": total_phases(s.phases.snapshot() for s in scheds)}
         if batcher is not None:
-            # the batched wave compute runs in the batcher, not in any one
-            # engine's dispatch: fold it into the aggregate so cloud_time
-            # stays comparable with non-batched runs (it cannot be
-            # attributed per client — per_client entries carry only each
-            # stream's own admit/submit time)
-            out["stats"].cloud_time += batcher.stats.cloud_time
             out["batcher"] = batcher.stats.as_row()
         return out
 
@@ -2575,7 +2584,6 @@ class ServingSystem:
 
         if mode == "cloud":
             caches = model.init_cache(1, max_seq)
-            t0 = time.perf_counter()
             x, _, caches, _ = model.prefill(params, batch, caches)
             tok = jnp.argmax(model.logits(params, x[:, -1:])[:, 0], -1)
             toks = [int(tok[0])]
@@ -2586,22 +2594,17 @@ class ServingSystem:
                     jnp.asarray(pos, jnp.int32))
                 toks.append(int(tok[0]))
                 pos += 1
-            st.cloud_time += time.perf_counter() - t0
             st.tokens = len(toks)
             return toks, st
 
         client = EdgeClient(collm, params, device_id, 1, max_seq)
-        t0 = time.perf_counter()
         decisions, h1_seq = client.prefill(batch)
-        st.edge_time += time.perf_counter() - t0
 
         prefill_logits = None
         if mode == "collm":
             enc = None  # enc-dec handled by uploading enc_out once (DESIGN)
-            t0 = time.perf_counter()
             prefill_logits = self.cloud.register(device_id, 1, max_seq,
                                                  h1_prompt=h1_seq, enc_out=enc)
-            st.cloud_time += time.perf_counter() - t0
             # prompt upload crosses the wire in the configured format
             st.upload_bytes += hidden_wire_bytes(
                 model.cfg.d_model, self.ccfg.wire_format,
@@ -2623,9 +2626,7 @@ class ServingSystem:
         st.tokens += 1
 
         for _ in range(max_new - 1):
-            t0 = time.perf_counter()
             out = client.step(jnp.asarray([[tok]], jnp.int32))
-            st.edge_time += time.perf_counter() - t0
             st.tokens += 1
             confs = {l: float(d.confidence[0])
                      for l, d in out.decisions.items()}
@@ -2658,11 +2659,9 @@ class ServingSystem:
                     st.exits_l2 += 1
                 tok = int(out.token[0])
             else:
-                t0 = time.perf_counter()
                 self.cloud.request(channel, device_id, client.pos - 1,
                                    backfill=self.ccfg.backfill)
                 (rep,) = channel.poll()
-                st.cloud_time += time.perf_counter() - t0
                 st.cloud_requests += 1
                 tok = int(jnp.argmax(rep.reply[0]))
             toks.append(tok)
