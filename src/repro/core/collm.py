@@ -17,7 +17,7 @@ Building blocks:
                            exactly (unit-tested invariant).
 
 All decode steps accept ``pos`` as a scalar or a per-row (B,) vector, and
-cloud compute is gated per row (``cloud_step_masked`` merges cache updates
+cloud compute is gated per row (``cloud_step_masked`` writes cache updates
 only for below-θ rows) — the primitives behind the continuous-batching
 scheduler in ``repro.serving.engine``.  Every step also takes an optional
 ``block_tbl`` for the block-paged KV layout
@@ -402,12 +402,13 @@ class CoLLM:
                            old: Dict[int, Pytree]) -> Dict[int, Pytree]:
         """Per-row cache merge: rows with mask=True take ``new``, others keep
         ``old``.  Stacked segments carry batch at axis 1, shared at axis 0.
-        Paged self-attention nodes are passed through untouched: their
-        masked rows already wrote to the trash page, so ``new`` is correct
-        for every row without a merge."""
+        Attention K/V nodes are passed through untouched: the step dropped
+        the masked rows' writes (dense rings) or sent them to the trash page
+        (paged), so ``new`` is correct for every row without a merge.  Only
+        recurrent state, which every row advances, is merged."""
         def merge(a: Pytree, b: Pytree, axis: int) -> Pytree:
             if isinstance(a, dict):
-                if "kp" in a:
+                if "kp" in a or ("k" in a and "pos" in a):
                     return a
                 return {k: merge(a[k], b[k], axis) for k in a}
             return _where_rows(mask, a, b, axis)
@@ -426,9 +427,13 @@ class CoLLM:
         """Batched cloud step serving only the below-θ rows: rows with
         mask=False keep their caches untouched (their upload was not
         consumed), preserving the per-client release/gap semantics of the
-        sequential path.  One call serves every needy row of a step.  With
-        paged caches the mask becomes the KV ``write_mask`` (masked rows
-        write to the trash page) and only non-paged state is merged."""
+        sequential path.  One call serves every needy row of a step.  The
+        mask becomes the KV ``write_mask``: dense rings drop the masked
+        rows' writes at the scatter (and the layer loop updates each
+        stacked cache in place), paged caches send them to the trash
+        page, and only recurrent state is merged.  Jitted through
+        ``mesh_exec.jit_step`` the step donates ``caches``: take the
+        returned caches and never reuse the ones passed in."""
         logits, new_caches = self.cloud_step(params, upload, caches, pos,
                                              block_tbl=block_tbl,
                                              write_mask=mask)
@@ -446,8 +451,9 @@ class CoLLM:
         write is harmless (the slot is overwritten before it is read when
         the row resumes), but recurrent state would advance irreversibly —
         so rows with ``run_mask=False`` keep their caches bit-for-bit
-        (paged self-attention writes to the trash page via the KV
-        ``write_mask``; everything else is merged per row)."""
+        (through the KV ``write_mask`` dense rings drop their attention
+        writes and paged ones send them to the trash page; recurrent
+        state is merged per row)."""
         x, exit_h, new_caches = self.model.decode_step(
             params, token, caches, pos, self.edge_segs, block_tbl=block_tbl,
             write_mask=run_mask)

@@ -412,11 +412,22 @@ def decode_attention(params: Params, cfg: ModelConfig, x: jax.Array,
                      cache: Params, pos: jax.Array, *,
                      window: int = 0, use_rope: bool = True,
                      cross: bool = False,
-                     update_cache: bool = True) -> Tuple[jax.Array, Params]:
+                     update_cache: bool = True,
+                     write_mask: Optional[jax.Array] = None,
+                     layer: Optional[jax.Array] = None
+                     ) -> Tuple[jax.Array, Params]:
     """Single-token decode.  x: (B,1,d); pos: scalar int32 position or a
     per-row (B,) position vector (continuous batching: every row decodes at
     its own sequence offset).  For ``cross=True`` the cache holds precomputed
-    encoder kv (no update)."""
+    encoder kv (no update).
+
+    ``write_mask`` (B,) bool drops the KV write of every row where it is
+    False: its slot index is sent out of range and the scatter drops it, so
+    that row's ``k``/``v``/``pos`` stay bit-for-bit as they were (the masked
+    cloud step needs no cache merge afterwards).  ``layer`` makes ``cache``
+    a whole stacked segment cache (leaves ``(L, B, ...)``): the new entries
+    go in at ``[layer, row, slot]`` and attention reads layer ``layer`` of
+    it, so a layer loop can carry the stack and update it in place."""
     b = x.shape[0]
     hd = cfg.resolved_head_dim
     h, kvh = cfg.n_heads, cfg.n_kv_heads
@@ -428,10 +439,8 @@ def decode_attention(params: Params, cfg: ModelConfig, x: jax.Array,
     if use_rope and not cross:
         q = apply_rope(q, pos_b[:, None], cfg.rope_theta)
 
-    if cross:
-        k, v, kpos = cache["k"], cache["v"], cache["pos"]
-        new_cache = cache
-    else:
+    new_cache = cache
+    if not cross:
         knew = jnp.einsum("bsd,de->bse", x, params["wk"].astype(x.dtype))
         vnew = jnp.einsum("bsd,de->bse", x, params["wv"].astype(x.dtype))
         if "bk" in params:
@@ -443,17 +452,22 @@ def decode_attention(params: Params, cfg: ModelConfig, x: jax.Array,
             knew = apply_rope(knew, pos_b[:, None], cfg.rope_theta)
         if update_cache:
             with jax.named_scope("kv_write"):
-                size = cache["k"].shape[1]
+                size = cache["k"].shape[-3]
                 slot = (pos_b % size).astype(jnp.int32)
+                if write_mask is not None:
+                    slot = jnp.where(write_mask, slot, size)
                 bidx = jnp.arange(b)
-                ck = cache["k"].at[bidx, slot].set(
-                    knew[:, 0].astype(cache["k"].dtype))
-                cv = cache["v"].at[bidx, slot].set(
-                    vnew[:, 0].astype(cache["v"].dtype))
-                cp = cache["pos"].at[bidx, slot].set(pos_b)
-            cache = {"k": ck, "v": cv, "pos": cp}
-        k, v, kpos = cache["k"], cache["v"], cache["pos"]
-        new_cache = cache
+                idx = (bidx, slot) if layer is None else (layer, bidx, slot)
+                ck = cache["k"].at[idx].set(
+                    knew[:, 0].astype(cache["k"].dtype), mode="drop")
+                cv = cache["v"].at[idx].set(
+                    vnew[:, 0].astype(cache["v"].dtype), mode="drop")
+                cp = cache["pos"].at[idx].set(pos_b, mode="drop")
+            new_cache = {"k": ck, "v": cv, "pos": cp}
+    k, v, kpos = new_cache["k"], new_cache["v"], new_cache["pos"]
+    if layer is not None:
+        k, v, kpos = (jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
+                      for a in (k, v, kpos))
 
     g = h // kvh
     qg = q.reshape(b, kvh, g, hd)

@@ -235,10 +235,14 @@ def block_forward(params: Params, cfg: ModelConfig, kind: str, x: jax.Array,
 # single-token decode
 # ---------------------------------------------------------------------------
 def block_decode(params: Params, cfg: ModelConfig, kind: str, x: jax.Array,
-                 cache: Params, ctx: BlockCtx) -> Tuple[jax.Array, Params]:
+                 cache: Params, ctx: BlockCtx,
+                 layer: Optional[jax.Array] = None
+                 ) -> Tuple[jax.Array, Params]:
     """Single-token decode; paged caches also accept a multi-token chunk
     (``x``: (B,C,d) with ``ctx.pos`` the chunk's first position and
-    ``ctx.write_mask`` optionally (B,C)) — the chunked-prefill path."""
+    ``ctx.write_mask`` optionally (B,C)) — the chunked-prefill path.
+    ``layer`` (dense attention caches only) makes ``cache`` the whole
+    stacked segment cache, read and written in place at that layer."""
     if kind in (DENSE, SHARED_ATTN, MOE):
         with jax.named_scope("attention"):
             h = _norm(x, params, cfg, "ln1")
@@ -256,7 +260,9 @@ def block_decode(params: Params, cfg: ModelConfig, kind: str, x: jax.Array,
                 att, new_self = decode_attention(params["attn"], cfg, h,
                                                  cache["self"], ctx.pos,
                                                  window=ctx.window,
-                                                 use_rope=cfg.use_rope)
+                                                 use_rope=cfg.use_rope,
+                                                 write_mask=ctx.write_mask,
+                                                 layer=layer)
         # same mid-block sequence-parallel point as block_forward (no-op
         # for S=1 decode; load-bearing for page-sized prefill chunks)
         x = constrain_residual(x + att)
@@ -266,7 +272,7 @@ def block_decode(params: Params, cfg: ModelConfig, kind: str, x: jax.Array,
             hx = _norm(x, params, cfg, "lnx")
             catt, _ = decode_attention(params["cross"], cfg, hx,
                                        cache["cross"], ctx.pos, cross=True,
-                                       use_rope=False)
+                                       use_rope=False, layer=layer)
             x = x + catt
         with jax.named_scope("mlp"):
             h2 = _norm(x, params, cfg, "ln2")

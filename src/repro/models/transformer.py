@@ -67,6 +67,12 @@ def build_segments(cfg: ModelConfig) -> Tuple[SegmentSpec, ...]:
     return tuple(segs)
 
 
+def _dense_kv(cache: Params) -> bool:
+    """True for an attention block's cache whose self-attention K/V is a
+    dense ring (not pages, not recurrent state)."""
+    return isinstance(cache, dict) and "k" in cache.get("self", {})
+
+
 def _stack(trees: Sequence[Params]) -> Params:
     return jax.tree.map(lambda *xs: jnp.stack(xs, axis=0), *trees)
 
@@ -297,6 +303,21 @@ class Model:
             if seg.shared:
                 x, nc = block_decode(p, cfg, seg.kind, x, cache, sctx)
                 x = shardlib.constrain_residual(x)
+            elif ctx.write_mask is not None and _dense_kv(cache):
+                # masked step over dense KV: the stacked cache rides in the
+                # carry and each layer writes its served rows' entries in
+                # place at [layer, row, slot].  As scan xs/ys it would be
+                # sliced out per layer and rebuilt as a fresh stack, a whole
+                # copy of the segment's cache per step.
+                def body(carry, inp):
+                    h, c = carry
+                    lp, i = inp
+                    h, c = block_decode(lp, cfg, seg.kind, h, c, sctx,
+                                        layer=i)
+                    return (shardlib.constrain_residual(h), c), None
+
+                (x, nc), _ = jax.lax.scan(
+                    body, (x, cache), (p, jnp.arange(seg.length)))
             else:
                 def body(h, inp):
                     lp, lc = inp

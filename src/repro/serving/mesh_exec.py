@@ -49,6 +49,12 @@ CLOUD_STEPS = frozenset({
 })
 
 
+# Steps whose caches (argument 2) are donated: they update their KV in
+# place, so the caller must take the returned caches and never read the
+# ones it passed in again.
+DONATES_CACHES = frozenset({"cloud_step_masked"})
+
+
 class MeshContext:
     """Owns a cloud mesh + policy and the per-CoLLM jitted step cache."""
 
@@ -80,7 +86,8 @@ class MeshContext:
             counts[name] += 1            # runs only while jax traces
             return fn(*a, **kw)
 
-        jf = jax.jit(traced)
+        jf = jax.jit(traced,
+                     donate_argnums=(2,) if name in DONATES_CACHES else ())
         self._jitted[name] = jf
         if self.policy is not None and name in CLOUD_STEPS:
             policy = self.policy

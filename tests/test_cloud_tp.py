@@ -14,6 +14,8 @@ Run the multi-device tests with
 (they skip on fewer than 8 devices; the single-device-default tests run
 anywhere).
 """
+import re
+
 import jax
 import numpy as np
 import pytest
@@ -23,7 +25,7 @@ from repro.core.collm import CollmConfig
 from repro.launch import sharding as shardlib
 from repro.models.registry import build_model
 from repro.serving.engine import ServingSystem
-from repro.serving.mesh_exec import mesh_context
+from repro.serving.mesh_exec import jit_step, mesh_context
 
 CLOUD_MESH = (2, 4)
 
@@ -139,6 +141,26 @@ def test_tp_param_bytes_shrink(tp):
     assert actual == pytest.approx(est, rel=1e-6)
     # most weight is model-axis sharded (wk/wv + norms replicate)
     assert actual < 0.6 * total
+
+
+@needs_mesh
+def test_tp_masked_cloud_step_aliases_its_sharded_caches(tp):
+    """On the mesh the donated cloud caches come back with the shardings
+    they went in with, so every leaf is updated in place."""
+    sys_tp = _system(tp, cloud_mesh=CLOUD_MESH)
+    collm, mc = sys_tp.collm, mesh_context(sys_tp.collm)
+    b, d = 4, tp["cfg"].d_model
+    caches = mc.shard_caches(collm.init_cloud_cache(b, 32), batch=b)
+    jit_step(collm, "cloud_step_masked")
+    with shardlib.use_policy(mc.policy):
+        hlo = mc.jitted("cloud_step_masked").lower(
+            sys_tp.params, {"data": np.zeros((b, 1, d), np.float16)},
+            caches, np.arange(b, dtype=np.int32), np.ones((b,), bool),
+            None).compile().as_text()
+    aliases = re.search(r"input_output_alias=\{(.*?)\}, entry", hlo)
+    assert aliases is not None
+    assert len(re.findall(r"may-alias|must-alias", aliases.group(1))) == \
+        len(jax.tree.leaves(caches))
 
 
 # ---------------------------------------------------------------------------
