@@ -11,7 +11,8 @@ the slots' ``req``/``active``/``tokens``/``events``/``stats`` fields).
 
 Weights come from ``bench.weights`` (not from the program's own init) and
 are laid out into the program's parameter tree in one jitted call, in the
-served dtype.
+served dtype; the architecture's adapter (``bench.arch``) names the
+program's leaves and builds its ``ModelConfig``.
 """
 from __future__ import annotations
 
@@ -26,18 +27,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench import arch
 from bench import weights as W
 from bench.traffic import Req, Traffic, warmup_lengths
 
-# program parameter leaves -> names of bench.weights
-_SEGMENT_LEAVES = {
-    ("attn", "wq"): "attn/wq", ("attn", "wk"): "attn/wk",
-    ("attn", "wv"): "attn/wv", ("attn", "wo"): "attn/wo",
-    ("mlp", "w_gate"): "mlp/w_gate", ("mlp", "w_up"): "mlp/w_up",
-    ("mlp", "w_down"): "mlp/w_down",
-    ("ln1_scale",): "ln1", ("ln2_scale",): "ln2",
-}
-_DTYPES = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
 # warm-up serves at least this many rounds (so that every step kind, the
 # cloud step among them, has run with near certainty) and stops at the
 # first later round that traces nothing new
@@ -58,6 +51,7 @@ def build_params(model, cfg: Dict, key: jax.Array):
     specs = model.param_specs()
     segs = model.segments
     dtype = model.param_dtype
+    leaves = arch.of(cfg).LEAVES
 
     def make(key, path, spec):
         p = _path(path)
@@ -65,9 +59,9 @@ def build_params(model, cfg: Dict, key: jax.Array):
             x = W.tensor(cfg, key, p[0], 0, dtype)
         elif p[0] == "exit_norms":
             x = W.tensor(cfg, key, "exit_norm", int(p[1]), dtype)
-        elif p[0] == "segments" and p[2:] in _SEGMENT_LEAVES:
+        elif p[0] == "segments" and p[2:] in leaves:
             seg = segs[p[1]]
-            name = _SEGMENT_LEAVES[p[2:]]
+            name = leaves[p[2:]]
             x = jax.vmap(lambda l: W.tensor(cfg, key, name, l, dtype))(
                 jnp.arange(seg.start, seg.end))
         else:
@@ -76,7 +70,9 @@ def build_params(model, cfg: Dict, key: jax.Array):
         if x.shape != spec.shape:
             raise ValueError(f"parameter {p}: program shape {spec.shape}, "
                              f"configuration shape {x.shape}")
-        return x
+        # drawn in the served dtype; a leaf the program keeps wider (a
+        # router in float32) holds the same values
+        return x.astype(spec.dtype)
 
     return jax.jit(lambda k: jax.tree_util.tree_map_with_path(
         lambda path, spec: make(k, path, spec), specs))(key)
@@ -84,17 +80,7 @@ def build_params(model, cfg: Dict, key: jax.Array):
 
 def model_config(cfg: Dict):
     """The program's ``ModelConfig`` for a configuration file."""
-    from repro.configs.base import ModelConfig
-    m = W.dims(cfg)
-    return ModelConfig(
-        name=cfg["name"], arch_type="dense", n_layers=m["layers"],
-        d_model=m["d"], n_heads=m["h"], n_kv_heads=m["kv"],
-        head_dim=m["hd"], d_ff=m["ff"], vocab_size=m["vocab"],
-        rope_theta=float(cfg["rope_theta"]),
-        norm_eps=float(cfg["rms_norm_eps"]),
-        tie_embeddings=bool(cfg["tie_word_embeddings"]),
-        exit_layers=tuple(cfg["assumed"]["exit_layers"]),
-        source=cfg["source"]).validate()
+    return arch.of(cfg).model_config(cfg)
 
 
 class Served:
@@ -110,7 +96,7 @@ class Served:
 
         self.cfg, self.cell = cfg, cell
         self.model = Model(model_config(cfg),
-                           param_dtype=_DTYPES[cfg["torch_dtype"]])
+                           param_dtype=W.served_dtype(cfg))
         ccfg = CollmConfig(theta=float(cell["theta"]),
                            wire_format=cfg["assumed"]["wire_format"],
                            kv_layout=cell["kv_layout"])

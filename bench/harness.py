@@ -156,9 +156,15 @@ def run(c: Dict, *, seed: int, seconds: float, trace_on: bool,
     loop.sched = None
     served.close()
     del loop, served
+    readers = {name: spec.reader(name)
+               for name in (c["per_layer"] if trace_on else c["end_to_end"])}
     summary = None
     if trace_on:
         summary = trace.reduce_dir(str(TRACE_DIR))
+        if summary is not None and any(getattr(m, "SCOPES", False)
+                                       for m in readers.values()):
+            summary["scopes"] = trace.scopes(
+                trace.find_xplane(str(TRACE_DIR)))
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
     t_ref = time.perf_counter()
     free = jax.devices()[0].memory_stats() or {}
@@ -170,8 +176,8 @@ def run(c: Dict, *, seed: int, seconds: float, trace_on: bool,
             setup_s=t_open - t_start, t0=win["t0"], t_end=win["t_end"],
             records=records, ticks=ticks, trace=summary, peaks=peaks)
     metrics = {}
-    for name in (c["per_layer"] if trace_on else c["end_to_end"]):
-        value = spec.reader(name).read(r)
+    for name, reader in readers.items():
+        value = reader.read(r)
         if value is not None:
             metrics[name] = {"value": float(value), "unit": c["units"][name]}
     due = [x for x in records if x.due < win["t_end"]]

@@ -9,9 +9,9 @@ at the engine spans' edges and each piece is credited to the innermost
 span over it, or to ``outside`` where no engine span is open (the
 harness's own code between calls, and its waits for arrivals).  The
 pieces partition the window's idle time, so the phases' ``idle_seconds``
-sum to it.  ``device_scopes`` puts the device's time down to the steps'
-``jax.named_scope``s (``attention``, ``mlp``, ``exit_heads``,
-``output_head``).
+sum to it.  ``device_scopes`` (from ``bench.trace``) puts the device's
+time down to the steps' ``jax.named_scope``s (``attention``, ``mlp``,
+``exit_heads``, ``output_head``).
 
 Run as a script, it serves one cell's window as ``bench/run.py`` does
 (the same build, warm-up and traffic; no reference check) and prints one
@@ -43,11 +43,11 @@ if __name__ == "__main__":
     sys.path[:0] = [str(_ROOT), str(_ROOT / "src")]
 
 from bench import spec, trace  # noqa: E402
+# the device-scope reduction lives in bench.trace; re-exported
+from bench.trace import (JAX_COMPONENTS, device_scopes, op_scope,  # noqa: E402,F401
+                         scopes_from_hlo_stats)
 
 ENGINE = "engine."
-# name-stack components JAX adds around user code
-JAX_COMPONENTS = ("while", "body", "cond", "closed_call", "checkpoint",
-                  "remat")
 TRACE_DIR = spec.ROOT / ".bench_cache" / "phase_trace"
 OUTSIDE = "outside"
 # a tick or admission longer than this is reported under ``stalls``
@@ -170,47 +170,6 @@ def reduce_phases(pd) -> Optional[Dict]:
     if stalls:
         result["stalls"] = stalls
     return result
-
-
-def op_scope(framework_name: str) -> Tuple[str, str]:
-    """(program, top named scope) of an XLA op from its framework name:
-    ``jit(edge_step)/while/body/closed_call/attention/kv_write/scatter:``
-    -> ``("edge_step", "attention")``.  JAX's own components (``jit(...)``,
-    loop and call bodies) are skipped, and the op itself is not a scope;
-    an op outside every named scope gets ``"-"``."""
-    parts = framework_name.split("/")
-    head = parts[0]
-    program = head[4:-1] if head.startswith("jit(") else head
-    for p in parts[1:-1]:
-        if "(" not in p and p not in JAX_COMPONENTS:
-            return program, p
-    return program, "-"
-
-
-def scopes_from_hlo_stats(table: Dict) -> Dict[str, float]:
-    """Device seconds by ``program:scope`` from xprof's ``hlo_stats``
-    table (one row per XLA op).  Self time, so a loop or call adds only
-    what its body's ops do not."""
-    cols = [c["id"] for c in table["cols"]]
-    name, self_us = cols.index("tf_op_name"), cols.index("total_self_time")
-    out: Dict[str, float] = collections.defaultdict(float)
-    for row in table["rows"]:
-        cells = [c.get("v") for c in row["c"]]
-        if cells[name]:
-            program, scope = op_scope(cells[name])
-            out[f"{program}:{scope}"] += cells[self_us] * 1e-6
-    return dict(sorted(out.items(), key=lambda kv: -kv[1]))
-
-
-def device_scopes(xplane: str) -> Dict[str, float]:
-    """``scopes_from_hlo_stats`` of a trace file, empty where the trace
-    has no device ops.  The ops' framework names live in the trace's event
-    metadata, which ``ProfileData`` does not expose; xprof's converter
-    reads them, so this raises ImportError without the ``xprof``
-    package."""
-    from xprof.convert import raw_to_tool_data
-    data, _ = raw_to_tool_data.xspace_to_tool_data([xplane], "hlo_stats", {})
-    return scopes_from_hlo_stats(json.loads(data))
 
 
 def _split(s: float, e: float, name: str, pieces: List[Span]) -> Dict:

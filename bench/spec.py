@@ -65,7 +65,10 @@ def cell(name: str, root: pathlib.Path = ROOT) -> Dict:
 
 def reader(metric: str, root: pathlib.Path = ROOT):
     """The module ``bench/metrics/<metric>.py``; its ``read(run)`` returns
-    the metric's value, or None where the run holds nothing to read."""
+    the metric's value, or None where the run holds nothing to read.  A
+    reader of device time by named scope sets ``SCOPES = True``: only
+    then does a traced run fill ``run.trace["scopes"]`` (``trace.scopes``,
+    some tens of seconds of xprof conversion)."""
     path = root / "bench" / "metrics" / f"{metric}.py"
     if not path.exists():
         raise SpecError(f"no reader {path}")

@@ -6,19 +6,26 @@ Reads the ``.xplane.pb`` that ``jax.profiler`` writes, with
 execution of a compiled program (``jit_<name>``) and the line of XLA ops
 one event per operation.  The host plane carries the harness's own
 annotations (``bench.<activity>``), which name what the host was doing
-while the device sat idle.
+while the device sat idle.  Device time by the steps' ``jax.named_scope``s
+(``scopes``) comes from the ops' framework names, which only xprof's
+converter reads, at some tens of seconds a traced window.
 """
 from __future__ import annotations
 
 import bisect
 import collections
 import glob
+import json
 import os
 import re
+import sys
 from typing import Dict, List, Optional, Tuple
 
 HOST_PREFIX = "bench."
 WINDOW = "bench.window"
+# name-stack components JAX adds around user code
+JAX_COMPONENTS = ("while", "body", "cond", "closed_call", "checkpoint",
+                  "remat")
 
 Interval = Tuple[float, float]
 
@@ -135,6 +142,68 @@ def reduce_profile(pd) -> Optional[Dict]:
         "idle_gaps": sorted(([k, v] for k, v in idle.items()),
                             key=lambda kv: -kv[1])[:10],
     }
+
+
+def op_scope(framework_name: str) -> Tuple[str, str]:
+    """(program, top named scope) of an XLA op from its framework name:
+    ``jit(edge_step)/while/body/closed_call/attention/kv_write/scatter:``
+    -> ``("edge_step", "attention")``.  JAX's own components (``jit(...)``,
+    loop and call bodies) are skipped, and the op itself is not a scope;
+    an op outside every named scope gets ``"-"``."""
+    parts = framework_name.split("/")
+    head = parts[0]
+    program = head[4:-1] if head.startswith("jit(") else head
+    for p in parts[1:-1]:
+        if "(" not in p and p not in JAX_COMPONENTS:
+            return program, p
+    return program, "-"
+
+
+def scopes_from_hlo_stats(table: Dict) -> Dict[str, float]:
+    """Device seconds by ``program:scope`` from xprof's ``hlo_stats``
+    table (one row per XLA op).  Self time, so a loop or call adds only
+    what its body's ops do not.  An op the compiler made has no framework
+    name (a copy, a broadcast that fills a buffer): it counts under
+    ``-`` of the program that its ``program_id`` names on another row, so
+    that a program's scopes sum to its device time."""
+    cols = [c["id"] for c in table["cols"]]
+    name, self_us = cols.index("tf_op_name"), cols.index("total_self_time")
+    pid = cols.index("program_id") if "program_id" in cols else None
+    rows = [[c.get("v") for c in row["c"]] for row in table["rows"]]
+    programs = {r[pid]: op_scope(r[name])[0] for r in rows
+                if pid is not None and r[name]}
+    out: Dict[str, float] = collections.defaultdict(float)
+    for r in rows:
+        if r[name]:
+            program, scope = op_scope(r[name])
+        elif pid is not None and r[pid] in programs:
+            program, scope = programs[r[pid]], "-"
+        else:
+            continue
+        out[f"{program}:{scope}"] += r[self_us] * 1e-6
+    return dict(sorted(out.items(), key=lambda kv: -kv[1]))
+
+
+def device_scopes(xplane: str) -> Dict[str, float]:
+    """``scopes_from_hlo_stats`` of a trace file, empty where the trace
+    has no device ops.  The ops' framework names live in the trace's event
+    metadata, which ``ProfileData`` does not expose; xprof's converter
+    reads them, so this raises ImportError without the ``xprof``
+    package."""
+    from xprof.convert import raw_to_tool_data
+    data, _ = raw_to_tool_data.xspace_to_tool_data([xplane], "hlo_stats", {})
+    return scopes_from_hlo_stats(json.loads(data))
+
+
+def scopes(xplane: str) -> Optional[Dict[str, float]]:
+    """``device_scopes`` of a trace file, or None where they cannot be
+    had: without ``xprof``, or where its converter fails on the trace
+    (the reason goes to standard error)."""
+    try:
+        return device_scopes(xplane)
+    except Exception as e:  # noqa: BLE001 - a missing or failing converter
+        print(f"bench: device scopes unavailable: {e!r}", file=sys.stderr)
+        return None
 
 
 def reduce_dir(trace_dir: str) -> Optional[Dict]:
