@@ -22,11 +22,49 @@ SHARED_ATTN = "shared_attn"  # Zamba2 shared transformer block
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
-    num_experts: int
+    num_experts: int               # the router's width: every routed expert
     top_k: int
     expert_d_ff: int
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25  # training's capacity dispatch only
     router_aux_coef: float = 0.01  # load-balance loss weight
+    n_shared_experts: int = 0      # experts every token passes through
+    norm_topk_prob: bool = True    # renormalise the top-k probabilities
+    # [first, first + count) of the routed experts this device holds (expert
+    # parallelism); None holds all.  Routing still runs over num_experts.
+    held: Optional[Tuple[int, int]] = None
+    first_dense_layers: int = 0    # leading layers with a dense MLP of d_ff
+
+    @property
+    def held_range(self) -> Tuple[int, int]:
+        """(first held expert, number held)."""
+        return self.held if self.held is not None else (0, self.num_experts)
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434): keys
+    and values are expanded from a cached latent ``c_kv`` of
+    ``kv_lora_rank`` values, beside one rotary key of ``qk_rope_head_dim``
+    shared by every head.  Queries are not compressed (no q-LoRA)."""
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rotary scaling (arXiv:2309.00071), with DeepSeek-V2's names."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -58,6 +96,8 @@ class ModelConfig:
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
     use_rope: bool = True          # False -> absolute (sinusoidal) positions
+    rope_scaling: Optional[YarnScaling] = None
+    mla: Optional[MLAConfig] = None    # latent attention in place of MHA/GQA
     norm_type: str = "rms"         # "rms" | "layernorm"
     mlp_kind: str = "gated_silu"   # "gated_silu" | "gelu"
     # --- mixture of experts -------------------------------------------------
@@ -103,7 +143,8 @@ class ModelConfig:
     def block_kinds(self) -> Tuple[str, ...]:
         """Per-layer block kind sequence for the *decoder* stack."""
         if self.arch_type == "moe":
-            return (MOE,) * self.n_layers
+            k = self.moe.first_dense_layers if self.moe else 0
+            return (DENSE,) * k + (MOE,) * (self.n_layers - k)
         if self.arch_type == "ssm":
             # xLSTM: sLSTM block at every 7th position per arXiv:2405.04517
             # ([1:7] sLSTM:mLSTM ratio for the 350M-class model family);
@@ -139,6 +180,9 @@ class ModelConfig:
             assert tuple(sorted(self.exit_layers)) == self.exit_layers
         if self.arch_type == "moe":
             assert self.moe is not None
+            first, count = self.moe.held_range
+            assert 0 <= first and 0 < count and first + count <= \
+                self.moe.num_experts, self.name
         return self
 
 
@@ -151,7 +195,7 @@ def reduced(cfg: ModelConfig, *, n_layers: int = 2, d_model: int = 256,
         kv -= 1
     moe = None
     if cfg.moe is not None:
-        moe = dataclasses.replace(cfg.moe, num_experts=4,
+        moe = dataclasses.replace(cfg.moe, num_experts=4, held=None,
                                   top_k=min(2, cfg.moe.top_k),
                                   expert_d_ff=max(64, d_model // 4))
     ssm = None

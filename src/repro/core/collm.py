@@ -39,6 +39,7 @@ import jax.numpy as jnp
 
 from repro.core.exits import ExitDecision, evaluate_exit, first_confident_exit
 from repro.core.transport import dequantize, quantize
+from repro.models.attention import is_ring
 from repro.models.transformer import Model
 
 Params = Dict[str, Any]
@@ -135,6 +136,9 @@ class EdgeStepOut(NamedTuple):
     exited: jax.Array           # (B,) bool
     upload: Dict[str, jax.Array]   # quantized l_ee1 hidden (wire packet)
     caches: Dict[int, Pytree]
+    # (B,) int32: each row's (token, expert) pairs that landed on held
+    # experts over the edge's moe layers; None for a model without experts
+    held: Optional[jax.Array] = None
 
 
 def _where_rows(pred: jax.Array, a: jax.Array, b: jax.Array,
@@ -163,6 +167,10 @@ class CoLLM:
         if ccfg.spec_k > 1 and not ccfg.speculative:
             raise ValueError("spec_k > 1 requires speculative=True "
                              "(drafting generalizes the speculative path)")
+        if cfg.mla is not None and ccfg.kv_layout != "dense":
+            raise ValueError(f"{cfg.name}: latent attention (MLA) keeps its "
+                             f"latent cache in the dense layout; "
+                             f'kv_layout={ccfg.kv_layout!r} is not supported')
         if ccfg.chunked_prefill and ccfg.kv_layout != "paged":
             raise ValueError('chunked_prefill=True requires kv_layout='
                              '"paged" (chunks ride the paged write path)')
@@ -309,7 +317,7 @@ class CoLLM:
         c = tokens.shape[1]
         wm = (jnp.arange(c, dtype=jnp.int32)[None, :]
               < jnp.asarray(chunk_len, jnp.int32))
-        x, exit_h, new_caches = self.model.decode_step(
+        x, exit_h, new_caches, _ = self.model.decode_step(
             params, tokens, caches, pos0, self.edge_segs,
             block_tbl=block_tbl, write_mask=wm)
         last = jnp.asarray(chunk_len, jnp.int32) - 1
@@ -329,7 +337,7 @@ class CoLLM:
         c = h1.shape[1]
         wm = (jnp.arange(c, dtype=jnp.int32)[None, :]
               < jnp.asarray(chunk_len, jnp.int32))
-        x, _, new_caches = self.model.decode_from_hidden(
+        x, _, new_caches, _ = self.model.decode_from_hidden(
             params, h1, caches, pos0, self.cloud_segs,
             block_tbl=block_tbl, write_mask=wm)
         last = jnp.asarray(chunk_len, jnp.int32) - 1
@@ -343,11 +351,11 @@ class CoLLM:
     def edge_step(self, params: Params, token: jax.Array,
                   caches: Dict[int, Pytree], pos: jax.Array,
                   block_tbl: Optional[jax.Array] = None) -> EdgeStepOut:
-        x, exit_h, new_caches = self.model.decode_step(
+        x, exit_h, new_caches, held = self.model.decode_step(
             params, token, caches, pos, self.edge_segs, block_tbl=block_tbl)
         decisions, tok, exited = self._exit_heads(params, exit_h)
         upload = quantize(exit_h[self.l_ee1], self.ccfg.wire_format)
-        return EdgeStepOut(decisions, tok, exited, upload, new_caches)
+        return EdgeStepOut(decisions, tok, exited, upload, new_caches, held)
 
     def _exit_heads(self, params: Params, exit_h: Dict[int, jax.Array]):
         """Every exit's decision over its hidden state, and the first
@@ -391,7 +399,7 @@ class CoLLM:
         Also used for KV backfill of early-exited positions.  ``pos`` may be
         a scalar or a per-row (B,) position vector."""
         hidden = dequantize(upload, self.model.compute_dtype)
-        x, _, new_caches = self.model.decode_from_hidden(
+        x, _, new_caches, _ = self.model.decode_from_hidden(
             params, hidden, caches, pos, self.cloud_segs,
             block_tbl=block_tbl, write_mask=write_mask)
         with jax.named_scope("output_head"):
@@ -408,7 +416,7 @@ class CoLLM:
         recurrent state, which every row advances, is merged."""
         def merge(a: Pytree, b: Pytree, axis: int) -> Pytree:
             if isinstance(a, dict):
-                if "kp" in a or ("k" in a and "pos" in a):
+                if "kp" in a or is_ring(a):
                     return a
                 return {k: merge(a[k], b[k], axis) for k in a}
             return _where_rows(mask, a, b, axis)
@@ -454,14 +462,14 @@ class CoLLM:
         (through the KV ``write_mask`` dense rings drop their attention
         writes and paged ones send them to the trash page; recurrent
         state is merged per row)."""
-        x, exit_h, new_caches = self.model.decode_step(
+        x, exit_h, new_caches, held = self.model.decode_step(
             params, token, caches, pos, self.edge_segs, block_tbl=block_tbl,
             write_mask=run_mask)
         decisions, tok, exited = self._exit_heads(params, exit_h)
         upload = quantize(exit_h[self.l_ee1], self.ccfg.wire_format)
         return EdgeStepOut(decisions, tok, exited, upload,
                            self._caches_where_rows(run_mask, new_caches,
-                                                   caches))
+                                                   caches), held)
 
     def invalidate_rows_after(self, caches: Dict[int, Pytree],
                               cut: jax.Array,
@@ -507,7 +515,7 @@ class CoLLM:
             if isinstance(c, dict):
                 if "kp" in c:
                     return fix_paged(c)
-                if "pos" in c and "k" in c:
+                if is_ring(c):
                     return fix_dense(c)
                 return {k: (go(v) if k != "cross" else v)
                         for k, v in c.items()}
@@ -583,7 +591,7 @@ class CoLLM:
                         caches: Dict[int, Pytree], pos: jax.Array,
                         block_tbl: Optional[jax.Array] = None):
         """Edge standalone (low-latency) mode: last exit is the output."""
-        x, exit_h, new_caches = self.model.decode_step(
+        x, exit_h, new_caches, _ = self.model.decode_step(
             params, token, caches, pos, self.edge_segs, block_tbl=block_tbl)
         d = evaluate_exit(self.model.exit_logits(params, self.l_ee2,
                                                  exit_h[self.l_ee2]))
@@ -593,7 +601,7 @@ class CoLLM:
                   caches: Dict[int, Pytree], pos: jax.Array,
                   block_tbl: Optional[jax.Array] = None):
         """Undivided model — the cloud-deployment baseline."""
-        x, _, new_caches = self.model.decode_step(
+        x, _, new_caches, _ = self.model.decode_step(
             params, token, caches, pos, collect_exits=False,
             block_tbl=block_tbl)
         logits = self.model.logits(params, x)[:, 0]

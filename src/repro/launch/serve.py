@@ -290,6 +290,9 @@ def main():
           f"cloud_requests={st.cloud_requests} "
           f"request_rate={st.request_rate:.2%}")
     print(f"upload={st.upload_bytes/1e3:.1f}KB")
+    if model.cfg.moe is not None and args.mode != "cloud":
+        print(f"held_expert_tokens={st.held_expert_tokens} (token, expert) "
+              f"pairs on held experts over the edge's moe layers")
     print("phases (calls/host s): " + " ".join(
         f"{name.removeprefix('engine.')}={v['calls']}/{v['seconds']:.3f}"
         for name, v in r["phases"].items()))

@@ -76,7 +76,8 @@ def make_step_fn(model: Model, shape: InputShape,
         return prefill_step
 
     def serve_step(params, token, caches, pos):
-        x, exit_h, new_caches = model.decode_step(params, token, caches, pos)
+        x, exit_h, new_caches, _ = model.decode_step(params, token, caches,
+                                                     pos)
         logits = model.logits(params, x)[:, 0]
         # exit heads are first-class: confidence computed every step
         confs = {}

@@ -12,6 +12,11 @@ layouts:
     ``repro.core.paging``); logical slot ``s`` always holds position ``s``
     (no wrap), unmapped rows write to the trash page.
 
+Multi-head latent attention (DeepSeek-V2: ``init_mla`` / ``mla_forward`` /
+``mla_decode``) caches one latent per position in a dense ring
+(``init_mla_cache``): prefill expands each head's keys and values from it,
+decode absorbs the expansion into the query and the output.
+
 Pure functions over explicit parameter pytrees.  The Pallas flash-decode
 kernels in ``repro.kernels.decode_attn`` mirror ``decode_attention`` (ring)
 and the paged gather (block table) and are validated against them.
@@ -25,7 +30,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models.common import apply_rope, dense_init, split_rngs
+from repro.models.common import (apply_rope, dense_init, rms_norm, split_rngs,
+                                 yarn_mscale)
 
 Params = Dict[str, Any]
 
@@ -102,13 +108,14 @@ def _direct_attention(q, k, v, qpos, kpos, *, causal, window, prefix_len,
     w = jax.nn.softmax(logits, axis=-1)
     w = jnp.where(jnp.isnan(w), 0.0, w)          # fully-masked rows
     out = jnp.einsum("bkgqs,bskd->bqkgd", w, v.astype(jnp.float32))
-    return out.reshape(b, sq, h, d).astype(q.dtype)
+    return out.reshape(b, sq, h, v.shape[-1]).astype(q.dtype)
 
 
 def _chunked_attention(q, k, v, qpos, kpos, *, causal, window, prefix_len,
                        scale, q_chunk=_Q_CHUNK, kv_chunk=_KV_CHUNK) -> jax.Array:
     """Two-level online-softmax scan; memory O(q_chunk * kv_chunk)."""
     b, sq, h, d = q.shape
+    dv = v.shape[-1]
     sk = k.shape[1]
     kvh = k.shape[2]
     g = h // kvh
@@ -129,7 +136,7 @@ def _chunked_attention(q, k, v, qpos, kpos, *, causal, window, prefix_len,
     @jax.checkpoint
     def q_body(_, qi):
         qblk, qp = qi                             # (b,qc,kvh,g,d), (qc,)
-        acc0 = jnp.zeros((b, kvh, g, q_chunk, d), jnp.float32)
+        acc0 = jnp.zeros((b, kvh, g, q_chunk, dv), jnp.float32)
         m0 = jnp.full((b, kvh, g, q_chunk), -jnp.inf, jnp.float32)
         l0 = jnp.zeros((b, kvh, g, q_chunk), jnp.float32)
 
@@ -160,7 +167,7 @@ def _chunked_attention(q, k, v, qpos, kpos, *, causal, window, prefix_len,
     _, outs = jax.lax.scan(q_body, None,
                            (jnp.moveaxis(qg, 1, 0), qpos_c))
     # outs: (nq, b, kvh, g, qc, d)
-    out = jnp.transpose(outs, (1, 0, 4, 2, 3, 5)).reshape(b, sq, h, d)
+    out = jnp.transpose(outs, (1, 0, 4, 2, 3, 5)).reshape(b, sq, h, dv)
     return out.astype(q.dtype)
 
 
@@ -222,25 +229,39 @@ def init_attn_cache(cfg: ModelConfig, batch: int, max_seq: int, *,
     }
 
 
+def is_ring(cache) -> bool:
+    """True for a dense ring cache node: keys and values, or MLA's latent."""
+    return (isinstance(cache, dict) and "pos" in cache
+            and ("k" in cache or "ckv" in cache))
+
+
 def _cache_write(cache: Params, k: jax.Array, v: jax.Array,
                  positions: jax.Array, offset) -> Params:
     """Write S new kv entries at ring positions (offset..offset+S-1) % size."""
-    size = cache["k"].shape[1]
-    s = k.shape[1]
+    return _ring_write(cache, {"k": k, "v": v}, positions, offset)
+
+
+def _ring_write(cache: Params, new: Params, positions: jax.Array,
+                offset) -> Params:
+    """Write S new entries of each leaf of ``new`` ((B, S, ...) each) at
+    ring positions (offset..offset+S-1) % size."""
+    b, s = next(iter(new.values())).shape[:2]
+    size = cache["pos"].shape[1]
     if s == size and isinstance(offset, int) and offset == 0:
         pos = jnp.broadcast_to(positions[None, :], cache["pos"].shape)
-        return {"k": k.astype(cache["k"].dtype),
-                "v": v.astype(cache["v"].dtype), "pos": pos.astype(jnp.int32)}
+        out = {n: x.astype(cache[n].dtype) for n, x in new.items()}
+        return {**out, "pos": pos.astype(jnp.int32)}
     if s > size:
         # only the last `size` entries survive in the ring
-        k, v, positions = k[:, s - size:], v[:, s - size:], positions[s - size:]
+        new = {n: x[:, s - size:] for n, x in new.items()}
+        positions = positions[s - size:]
         s = size
     idx = (positions % size).astype(jnp.int32)
-    ck = cache["k"].at[:, idx].set(k.astype(cache["k"].dtype))
-    cv = cache["v"].at[:, idx].set(v.astype(cache["v"].dtype))
-    cp = cache["pos"].at[:, idx].set(
-        jnp.broadcast_to(positions[None, :], (k.shape[0], s)).astype(jnp.int32))
-    return {"k": ck, "v": cv, "pos": cp}
+    out = {n: cache[n].at[:, idx].set(x.astype(cache[n].dtype))
+           for n, x in new.items()}
+    out["pos"] = cache["pos"].at[:, idx].set(
+        jnp.broadcast_to(positions[None, :], (b, s)).astype(jnp.int32))
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -652,3 +673,151 @@ def build_cross_cache(params: Params, cfg: ModelConfig,
     return {"k": k.reshape(b, sk, kvh, hd).astype(dt),
             "v": v.reshape(b, sk, kvh, hd).astype(dt),
             "pos": jnp.broadcast_to(jnp.arange(sk, dtype=jnp.int32)[None], (b, sk))}
+
+
+# ---------------------------------------------------------------------------
+# Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434)
+# ---------------------------------------------------------------------------
+def init_mla(rng: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> Params:
+    """``wq`` gives each head a query of ``qk_nope + qk_rope``; ``wkv_a``
+    gives the latent ``c_kv`` and the shared rotary key; ``kv_norm`` is the
+    latent's RMSNorm; ``wkv_b`` expands the latent into each head's key
+    (``qk_nope``) and value (``v_head_dim``)."""
+    mla, d, h = cfg.mla, cfg.d_model, cfg.n_heads
+    r = split_rngs(rng, 4)
+    return {
+        "wq": dense_init(r[0], d, h * mla.qk_head_dim, dtype),
+        "wkv_a": dense_init(r[1], d, mla.kv_lora_rank + mla.qk_rope_head_dim,
+                            dtype),
+        "kv_norm": jnp.zeros((mla.kv_lora_rank,), dtype),
+        "wkv_b": dense_init(r[2], mla.kv_lora_rank,
+                            h * (mla.qk_nope_head_dim + mla.v_head_dim),
+                            dtype),
+        "wo": dense_init(r[3], h * mla.v_head_dim, d, dtype),
+    }
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """qk_head_dim^-1/2, times YaRN's mscale(mscale_all_dim) squared."""
+    scale = cfg.mla.qk_head_dim ** -0.5
+    sc = cfg.rope_scaling
+    if sc is not None and sc.mscale_all_dim:
+        m = yarn_mscale(sc.factor, sc.mscale_all_dim)
+        scale *= m * m
+    return scale
+
+
+def init_mla_cache(cfg: ModelConfig, batch: int, max_seq: int,
+                   dtype=jnp.float32) -> Params:
+    """A dense ring of the latent: the normed ``c_kv`` and the rotated
+    shared key ``k_pe`` of each position, all heads' keys and values."""
+    mla = cfg.mla
+    return {"ckv": jnp.zeros((batch, max_seq, mla.kv_lora_rank), dtype),
+            "kpe": jnp.zeros((batch, max_seq, mla.qk_rope_head_dim), dtype),
+            "pos": jnp.full((batch, max_seq), -1, jnp.int32)}
+
+
+def _mla_project(params: Params, cfg: ModelConfig, x: jax.Array,
+                 positions: jax.Array):
+    """x (B, S, d) -> q_nope (B, S, H, nope), rotated q_pe (B, S, H, rope),
+    normed latent (B, S, rank) and rotated shared key (B, S, rope)."""
+    mla, h = cfg.mla, cfg.n_heads
+    b, s, _ = x.shape
+    q = jnp.einsum("bsd,de->bse", x, params["wq"].astype(x.dtype))
+    q = q.reshape(b, s, h, mla.qk_head_dim)
+    q_nope, q_pe = q[..., :mla.qk_nope_head_dim], q[..., mla.qk_nope_head_dim:]
+    kv = jnp.einsum("bsd,de->bse", x, params["wkv_a"].astype(x.dtype))
+    ckv = rms_norm(kv[..., :mla.kv_lora_rank], params["kv_norm"], cfg.norm_eps)
+    kpe = kv[..., mla.kv_lora_rank:][:, :, None, :]
+    q_pe = apply_rope(q_pe, positions, cfg.rope_theta, cfg.rope_scaling)
+    kpe = apply_rope(kpe, positions, cfg.rope_theta, cfg.rope_scaling)
+    return q_nope, q_pe, ckv, kpe[:, :, 0]
+
+
+def _mla_up(params: Params, cfg: ModelConfig, dtype):
+    """``wkv_b`` split per head: W_UK (rank, H, nope), W_UV (rank, H, v)."""
+    mla = cfg.mla
+    w = params["wkv_b"].astype(dtype).reshape(
+        mla.kv_lora_rank, cfg.n_heads, mla.qk_nope_head_dim + mla.v_head_dim)
+    return w[..., :mla.qk_nope_head_dim], w[..., mla.qk_nope_head_dim:]
+
+
+def mla_forward(params: Params, cfg: ModelConfig, x: jax.Array, *,
+                positions: Optional[jax.Array] = None,
+                cache: Optional[Params] = None,
+                cache_offset: int = 0) -> Tuple[jax.Array, Optional[Params]]:
+    """Causal full-sequence MLA (training / prefill), expanded: each head's
+    keys and values come from the latent through ``wkv_b``, and its key
+    gains the shared rotary part.  A ``cache`` receives the latent."""
+    mla, h = cfg.mla, cfg.n_heads
+    b, s, _ = x.shape
+    if positions is None:
+        positions = jnp.arange(s)
+    q_nope, q_pe, ckv, kpe = _mla_project(params, cfg, x, positions)
+    w_uk, w_uv = _mla_up(params, cfg, x.dtype)
+    k_nope = jnp.einsum("bsc,chn->bshn", ckv, w_uk)
+    v = jnp.einsum("bsc,chv->bshv", ckv, w_uv)
+    q = jnp.concatenate([q_nope, q_pe], -1)
+    k = jnp.concatenate([k_nope, jnp.broadcast_to(
+        kpe[:, :, None, :], (b, s, h, mla.qk_rope_head_dim))], -1)
+    core = _direct_attention if s * s <= _DIRECT_LIMIT else _chunked_attention
+    out = core(q, k, v, positions, positions, causal=True, window=0,
+               prefix_len=0, scale=mla_softmax_scale(cfg))
+    new_cache = None
+    if cache is not None:
+        with jax.named_scope("kv_write"):
+            new_cache = _ring_write(cache, {"ckv": ckv, "kpe": kpe},
+                                    positions, cache_offset)
+    y = jnp.einsum("bse,ed->bsd", out.reshape(b, s, h * mla.v_head_dim),
+                   params["wo"].astype(x.dtype))
+    return y, new_cache
+
+
+def mla_decode(params: Params, cfg: ModelConfig, x: jax.Array,
+               cache: Params, pos: jax.Array, *,
+               write_mask: Optional[jax.Array] = None,
+               layer: Optional[jax.Array] = None
+               ) -> Tuple[jax.Array, Params]:
+    """Single-token MLA decode, absorbed: ``q_nope`` is taken through
+    W_UK into the latent space and scored against the cached ``c_kv``
+    (plus ``q_pe`` against the cached rotary keys); the weighted latent
+    then goes through W_UV and ``wo``.  No head's key or value is
+    expanded, so each row reads its latent once for all heads.
+    ``pos``/``write_mask``/``layer`` as in ``decode_attention``."""
+    mla, h = cfg.mla, cfg.n_heads
+    b = x.shape[0]
+    pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
+    q_nope, q_pe, ckv, kpe = _mla_project(params, cfg, x, pos_b[:, None])
+    with jax.named_scope("kv_write"):
+        size = cache["ckv"].shape[-2]
+        slot = (pos_b % size).astype(jnp.int32)
+        if write_mask is not None:
+            slot = jnp.where(write_mask, slot, size)
+        bidx = jnp.arange(b)
+        idx = (bidx, slot) if layer is None else (layer, bidx, slot)
+        new_cache = {
+            "ckv": cache["ckv"].at[idx].set(
+                ckv[:, 0].astype(cache["ckv"].dtype), mode="drop"),
+            "kpe": cache["kpe"].at[idx].set(
+                kpe[:, 0].astype(cache["kpe"].dtype), mode="drop"),
+            "pos": cache["pos"].at[idx].set(pos_b, mode="drop")}
+    c, kr, kpos = new_cache["ckv"], new_cache["kpe"], new_cache["pos"]
+    if layer is not None:
+        c, kr, kpos = (jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
+                       for a in (c, kr, kpos))
+    w_uk, w_uv = _mla_up(params, cfg, x.dtype)
+    q_lat = jnp.einsum("bhn,chn->bhc", q_nope[:, 0], w_uk,
+                       preferred_element_type=jnp.float32)
+    logits = (jnp.einsum("bhc,bsc->bhs", q_lat, c.astype(jnp.float32))
+              + jnp.einsum("bhr,bsr->bhs", q_pe[:, 0].astype(jnp.float32),
+                           kr.astype(jnp.float32)))
+    logits = logits * mla_softmax_scale(cfg)
+    valid = (kpos >= 0) & (kpos <= pos_b[:, None])
+    logits = jnp.where(valid[:, None, :], logits, -jnp.inf)
+    w = jax.nn.softmax(logits, axis=-1)
+    w = jnp.where(jnp.isnan(w), 0.0, w)
+    o_lat = jnp.einsum("bhs,bsc->bhc", w, c.astype(jnp.float32))
+    o = jnp.einsum("bhc,chv->bhv", o_lat.astype(x.dtype), w_uv)
+    y = jnp.einsum("bse,ed->bsd", o.reshape(b, 1, h * mla.v_head_dim),
+                   params["wo"].astype(x.dtype))
+    return y, new_cache

@@ -4,11 +4,16 @@ Interface (kind in {dense, moe, mlstm, slstm, mamba2, shared_attn}):
 
     init_block(rng, cfg, kind)                     -> params for ONE layer
     block_forward(params, cfg, kind, x, ctx)       -> (x, aux, new_cache)
-    block_decode(params, cfg, kind, x, cache, ctx) -> (x, new_cache)
+    block_decode(params, cfg, kind, x, cache, ctx) -> (x, new_cache, held)
     init_block_cache(cfg, kind, batch, meta)       -> cache for ONE layer
 
 ``ctx`` carries positions / encoder output / layer meta (window, cross-attn)
 so stacked-scan callers can slice per-layer values.
+
+With ``cfg.mla`` the attention of dense and moe blocks is multi-head
+latent attention (``models/attention.py``; its cache is the latent) under
+the named scope ``mla``; a moe block's FFN runs under ``moe``, a dense
+one's under ``mlp``.
 """
 from __future__ import annotations
 
@@ -26,7 +31,9 @@ from repro.models import xlstm
 from repro.models.attention import (attention_forward, build_cross_cache,
                                     chunk_attention_paged, decode_attention,
                                     decode_attention_paged, init_attn_cache,
-                                    init_paged_attn_cache)
+                                    init_mla, init_mla_cache,
+                                    init_paged_attn_cache, mla_decode,
+                                    mla_forward)
 from repro.models.common import dense_init, layer_norm, rms_norm, split_rngs
 from repro.launch.sharding import constrain_residual
 
@@ -94,9 +101,10 @@ def init_block(rng: jax.Array, cfg: ModelConfig, kind: str,
     if with_cross is None:
         with_cross = cfg.is_encdec
     r = split_rngs(rng, 4)
+    attn = (init_mla(r[0], cfg, dtype) if cfg.mla is not None
+            else init_attention(r[0], cfg, dtype=dtype))
     if kind in (DENSE, SHARED_ATTN):
-        p: Params = {"attn": init_attention(r[0], cfg, dtype=dtype),
-                     "mlp": _mlp_init(r[1], cfg, dtype)}
+        p: Params = {"attn": attn, "mlp": _mlp_init(r[1], cfg, dtype)}
         for k, v in _init_norm(cfg, cfg.d_model, dtype).items():
             p["ln1" + k] = v
             p["ln2" + k] = v
@@ -106,8 +114,7 @@ def init_block(rng: jax.Array, cfg: ModelConfig, kind: str,
                 p["lnx" + k] = v
         return p
     if kind == MOE:
-        p = {"attn": init_attention(r[0], cfg, dtype=dtype),
-             "moe": moe_mod.init_moe(r[1], cfg, dtype)}
+        p = {"attn": attn, "moe": moe_mod.init_moe(r[1], cfg, dtype)}
         for k, v in _init_norm(cfg, cfg.d_model, dtype).items():
             p["ln1" + k] = v
             p["ln2" + k] = v
@@ -126,6 +133,8 @@ def init_block(rng: jax.Array, cfg: ModelConfig, kind: str,
 # ---------------------------------------------------------------------------
 def init_block_cache(cfg: ModelConfig, kind: str, batch: int, max_seq: int,
                      window: int, dtype=jnp.float32) -> Params:
+    if kind in (DENSE, SHARED_ATTN, MOE) and cfg.mla is not None:
+        return {"self": init_mla_cache(cfg, batch, max_seq, dtype=dtype)}
     if kind in (DENSE, SHARED_ATTN, MOE):
         c: Params = {"self": init_attn_cache(cfg, batch, max_seq,
                                              window=window, dtype=dtype)}
@@ -144,6 +153,10 @@ def init_block_cache_paged(cfg: ModelConfig, kind: str, batch: int,
     (no batch axis — rows address it through their block table); cross-attn
     and recurrent state stay dense per-row (fixed size, nothing to page).
     ``kv_dtype="int8"`` stores the pages quantized with per-row scales."""
+    if cfg.mla is not None:
+        raise ValueError(f"{cfg.name}: latent attention (MLA) caches its "
+                         f"latent in the dense layout only; use "
+                         f'kv_layout="dense"')
     if kind in (DENSE, SHARED_ATTN, MOE):
         c: Params = {"self": init_paged_attn_cache(cfg, num_pages, page_size,
                                                    dtype=dtype,
@@ -167,6 +180,32 @@ def _init_recurrent_cache(cfg: ModelConfig, kind: str, batch: int,
 
 
 # ---------------------------------------------------------------------------
+# the block's second half: a dense MLP or the experts
+# ---------------------------------------------------------------------------
+def _ffn(params: Params, cfg: ModelConfig, kind: str, x: jax.Array
+         ) -> Tuple[jax.Array, Optional[jax.Array]]:
+    """The served FFN after ``ln2``: (y, held pairs per row or None)."""
+    if kind == MOE:
+        with jax.named_scope("moe"):
+            return moe_mod.moe_served(params["moe"], cfg,
+                                      _norm(x, params, cfg, "ln2"))
+    with jax.named_scope("mlp"):
+        return _mlp(params["mlp"], cfg, _norm(x, params, cfg, "ln2")), None
+
+
+def _ffn_forward(params: Params, cfg: ModelConfig, kind: str, x: jax.Array,
+                 served: bool) -> Tuple[jax.Array, jax.Array]:
+    """Full-sequence FFN: (y, aux loss).  A prefill (``served``) runs the
+    served, dropless experts; training the capacity form."""
+    aux = jnp.zeros((), jnp.float32)
+    if kind == MOE and not served:
+        with jax.named_scope("moe"):
+            return moe_mod.moe_forward(params["moe"], cfg,
+                                       _norm(x, params, cfg, "ln2"))
+    return _ffn(params, cfg, kind, x)[0], aux
+
+
+# ---------------------------------------------------------------------------
 # full-sequence forward (train / prefill)
 # ---------------------------------------------------------------------------
 def block_forward(params: Params, cfg: ModelConfig, kind: str, x: jax.Array,
@@ -174,6 +213,16 @@ def block_forward(params: Params, cfg: ModelConfig, kind: str, x: jax.Array,
                   ) -> Tuple[jax.Array, jax.Array, Optional[Params]]:
     """Returns (x, aux_loss, new_cache)."""
     aux = jnp.zeros((), jnp.float32)
+    if kind in (DENSE, SHARED_ATTN, MOE) and cfg.mla is not None:
+        self_cache = cache.get("self") if cache else None
+        with jax.named_scope("mla"):
+            att, new_self = mla_forward(
+                params["attn"], cfg, _norm(x, params, cfg, "ln1"),
+                positions=ctx.positions, cache=self_cache,
+                cache_offset=ctx.cache_offset)
+        x = constrain_residual(x + att)
+        y, aux = _ffn_forward(params, cfg, kind, x, served=cache is not None)
+        return x + y, aux, (None if cache is None else {"self": new_self})
     if kind in (DENSE, SHARED_ATTN, MOE):
         self_cache = cache.get("self") if cache else None
         with jax.named_scope("attention"):
@@ -203,12 +252,7 @@ def block_forward(params: Params, cfg: ModelConfig, kind: str, x: jax.Array,
                 new_cache["cross"] = build_cross_cache(
                     params["cross"], cfg, ctx.enc_out,
                     dtype=cache["cross"]["k"].dtype)
-        with jax.named_scope("mlp"):
-            h2 = _norm(x, params, cfg, "ln2")
-            if kind == MOE:
-                y, aux = moe_mod.moe_forward(params["moe"], cfg, h2)
-            else:
-                y = _mlp(params["mlp"], cfg, h2)
+        y, aux = _ffn_forward(params, cfg, kind, x, served=cache is not None)
         return x + y, aux, new_cache
     if kind == MLSTM:
         if cache is not None:
@@ -237,12 +281,23 @@ def block_forward(params: Params, cfg: ModelConfig, kind: str, x: jax.Array,
 def block_decode(params: Params, cfg: ModelConfig, kind: str, x: jax.Array,
                  cache: Params, ctx: BlockCtx,
                  layer: Optional[jax.Array] = None
-                 ) -> Tuple[jax.Array, Params]:
+                 ) -> Tuple[jax.Array, Params, Optional[jax.Array]]:
     """Single-token decode; paged caches also accept a multi-token chunk
     (``x``: (B,C,d) with ``ctx.pos`` the chunk's first position and
     ``ctx.write_mask`` optionally (B,C)) — the chunked-prefill path.
     ``layer`` (dense attention caches only) makes ``cache`` the whole
-    stacked segment cache, read and written in place at that layer."""
+    stacked segment cache, read and written in place at that layer.
+    ``held`` is a moe block's (B,) count of each row's (token, expert)
+    pairs that landed on held experts, and None for other blocks."""
+    if kind in (DENSE, SHARED_ATTN, MOE) and cfg.mla is not None:
+        with jax.named_scope("mla"):
+            att, new_self = mla_decode(
+                params["attn"], cfg, _norm(x, params, cfg, "ln1"),
+                cache["self"], ctx.pos, write_mask=ctx.write_mask,
+                layer=layer)
+        x = constrain_residual(x + att)
+        y, held = _ffn(params, cfg, kind, x)
+        return x + y, {**cache, "self": new_self}, held
     if kind in (DENSE, SHARED_ATTN, MOE):
         with jax.named_scope("attention"):
             h = _norm(x, params, cfg, "ln1")
@@ -274,17 +329,12 @@ def block_decode(params: Params, cfg: ModelConfig, kind: str, x: jax.Array,
                                        cache["cross"], ctx.pos, cross=True,
                                        use_rope=False, layer=layer)
             x = x + catt
-        with jax.named_scope("mlp"):
-            h2 = _norm(x, params, cfg, "ln2")
-            if kind == MOE:
-                y = moe_mod.moe_forward_decode(params["moe"], cfg, h2)
-            else:
-                y = _mlp(params["mlp"], cfg, h2)
-        return x + y, new_cache
+        y, held = _ffn(params, cfg, kind, x)
+        return x + y, new_cache, held
     if kind == MLSTM:
-        return xlstm.mlstm_decode(params, cfg, x, cache)
+        return (*xlstm.mlstm_decode(params, cfg, x, cache), None)
     if kind == SLSTM:
-        return xlstm.slstm_decode(params, cfg, x, cache)
+        return (*xlstm.slstm_decode(params, cfg, x, cache), None)
     if kind == MAMBA2:
-        return m2.mamba2_decode(params, cfg, x, cache)
+        return (*m2.mamba2_decode(params, cfg, x, cache), None)
     raise ValueError(kind)

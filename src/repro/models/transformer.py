@@ -22,6 +22,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import DENSE, MOE, SHARED_ATTN, ModelConfig
 from repro.launch import sharding as shardlib
+from repro.models.attention import is_ring
 from repro.models.blocks import (BlockCtx, block_decode, block_forward,
                                  init_block, init_block_cache,
                                  init_block_cache_paged)
@@ -68,9 +69,33 @@ def build_segments(cfg: ModelConfig) -> Tuple[SegmentSpec, ...]:
 
 
 def _dense_kv(cache: Params) -> bool:
-    """True for an attention block's cache whose self-attention K/V is a
-    dense ring (not pages, not recurrent state)."""
-    return isinstance(cache, dict) and "k" in cache.get("self", {})
+    """True for an attention block's cache whose self-attention K/V (or
+    MLA latent) is a dense ring (not pages, not recurrent state)."""
+    return isinstance(cache, dict) and is_ring(cache.get("self"))
+
+
+def _experts_apart(seg: SegmentSpec, p: Params):
+    """A moe segment's scan inputs without its stacked expert weights, and
+    the stack.  A slice of the stack handed to the grouped-matmul kernel
+    would be a copy of the layer's experts; ``_layer_params`` instead hands
+    each layer the whole stack and its index, which the kernel reads in
+    place (``moe.moe_served``)."""
+    if seg.kind != MOE:
+        return p, None
+    moe = dict(p["moe"])
+    stack = {k: moe.pop(k) for k in ("w_gate", "w_up", "w_down")}
+    return {**p, "moe": moe}, stack
+
+
+def _layer_params(lp: Params, stack: Optional[Params], i) -> Params:
+    if stack is None:
+        return lp
+    return {**lp, "moe": {**lp["moe"], **stack, "layer": i}}
+
+
+def _layer_index(seg: SegmentSpec, stack: Optional[Params]):
+    """A scan input of layer indices where the layers need them."""
+    return None if stack is None else jnp.arange(seg.length)
 
 
 def _stack(trees: Sequence[Params]) -> Params:
@@ -267,15 +292,22 @@ class Model:
                 x = shardlib.constrain_residual(x)
                 aux_total = aux_total + aux
             else:
+                # a served (cached) moe segment reads its experts in place;
+                # training's capacity form takes them per layer
+                p, stack = (_experts_apart(seg, p) if cache is not None
+                            else (p, None))
+
                 def body(h, inp):
-                    lp, lc = inp
-                    h, aux, nc = block_forward(lp, cfg, seg.kind, h, sctx,
+                    lp, lc, i = inp
+                    h, aux, nc = block_forward(_layer_params(lp, stack, i),
+                                               cfg, seg.kind, h, sctx,
                                                cache=lc)
                     return shardlib.constrain_residual(h), (aux, nc)
 
                 if remat:
                     body = jax.checkpoint(body)
-                x, (auxs, nc) = jax.lax.scan(body, x, (p, cache))
+                x, (auxs, nc) = jax.lax.scan(
+                    body, x, (p, cache, _layer_index(seg, stack)))
                 aux_total = aux_total + jnp.sum(auxs)
             if cache is not None:
                 new_caches[si] = nc
@@ -286,11 +318,19 @@ class Model:
     def decode_segments(self, params: Params, x: jax.Array, ctx: BlockCtx,
                         seg_indices: Sequence[int], caches: Dict[int, Params],
                         collect_exits: bool = True):
-        """Single-token execution.  Returns (x, exit_hiddens, new_caches)."""
+        """Single-token execution.  Returns (x, exit_hiddens, new_caches,
+        held): ``held`` is the (B,) int32 count of each row's (token,
+        expert) pairs that landed on held experts, summed over the
+        segments' moe layers, and None where they have none."""
         cfg = self.cfg
         exit_set = set(cfg.exit_layers) if collect_exits else set()
         exit_hiddens: Dict[int, jax.Array] = {}
         new_caches: Dict[int, Params] = {}
+        held = None
+
+        def count(n):
+            return held if n is None else n if held is None else held + n
+
         # sequence-parallel constraint under an active ShardingPolicy:
         # a no-op for single-token decode (S=1 can't split), load-bearing
         # for the chunked-prefill path that decodes page-sized chunks
@@ -301,34 +341,43 @@ class Model:
             p = self._seg_params(params, si)
             cache = caches[si]
             if seg.shared:
-                x, nc = block_decode(p, cfg, seg.kind, x, cache, sctx)
+                x, nc, h = block_decode(p, cfg, seg.kind, x, cache, sctx)
                 x = shardlib.constrain_residual(x)
+                held = count(h)
             elif ctx.write_mask is not None and _dense_kv(cache):
                 # masked step over dense KV: the stacked cache rides in the
                 # carry and each layer writes its served rows' entries in
                 # place at [layer, row, slot].  As scan xs/ys it would be
                 # sliced out per layer and rebuilt as a fresh stack, a whole
                 # copy of the segment's cache per step.
+                p, stack = _experts_apart(seg, p)
+
                 def body(carry, inp):
                     h, c = carry
                     lp, i = inp
-                    h, c = block_decode(lp, cfg, seg.kind, h, c, sctx,
-                                        layer=i)
-                    return (shardlib.constrain_residual(h), c), None
+                    h, c, n = block_decode(_layer_params(lp, stack, i), cfg,
+                                           seg.kind, h, c, sctx, layer=i)
+                    return (shardlib.constrain_residual(h), c), n
 
-                (x, nc), _ = jax.lax.scan(
+                (x, nc), n = jax.lax.scan(
                     body, (x, cache), (p, jnp.arange(seg.length)))
+                held = count(None if n is None else n.sum(0))
             else:
-                def body(h, inp):
-                    lp, lc = inp
-                    h, nc = block_decode(lp, cfg, seg.kind, h, lc, sctx)
-                    return shardlib.constrain_residual(h), nc
+                p, stack = _experts_apart(seg, p)
 
-                x, nc = jax.lax.scan(body, x, (p, cache))
+                def body(h, inp):
+                    lp, lc, i = inp
+                    h, nc, n = block_decode(_layer_params(lp, stack, i), cfg,
+                                            seg.kind, h, lc, sctx)
+                    return shardlib.constrain_residual(h), (nc, n)
+
+                x, (nc, n) = jax.lax.scan(
+                    body, x, (p, cache, _layer_index(seg, stack)))
+                held = count(None if n is None else n.sum(0))
             new_caches[si] = nc
             if seg.end in exit_set:
                 exit_hiddens[seg.end] = x
-        return x, exit_hiddens, new_caches
+        return x, exit_hiddens, new_caches, held
 
     # ------------------------------------------------------------------
     # caches
@@ -394,7 +443,7 @@ class Model:
         def fix(c: Params) -> Params:
             if not isinstance(c, dict):
                 return c
-            if "pos" in c and "k" in c:            # self-attn ring cache
+            if is_ring(c):                         # self-attn ring cache
                 s = c["pos"].shape[-1]
                 keep = jnp.arange(s) < true_len
                 return {**c, "pos": jnp.where(keep, c["pos"], -1)}
@@ -467,7 +516,8 @@ class Model:
                     collect_exits: bool = True,
                     block_tbl: Optional[jax.Array] = None,
                     write_mask: Optional[jax.Array] = None):
-        """token: (B,1) -> (final hidden (B,1,d), exit_hiddens, caches).
+        """token: (B,1) -> (final hidden (B,1,d), exit_hiddens, caches,
+        held) as ``decode_segments``.
         ``pos`` is a scalar or a per-row (B,) position vector.  Paged caches
         additionally need ``block_tbl`` (B, max_logical); ``write_mask``
         (B,) bool redirects masked rows' KV writes to the trash page."""

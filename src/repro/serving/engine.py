@@ -111,6 +111,10 @@ class GenStats:
     cow_copies: int = 0
     prefill_chunks: int = 0
     upload_bytes: int = 0
+    # routed models: (token, expert) pairs of this stream's decoded tokens
+    # that landed on the experts this device holds, summed over the edge
+    # step's moe layers (read from the edge step's fetched outputs)
+    held_expert_tokens: int = 0
     stall_s: float = 0.0          # virtual time stalled on in-flight replies
     overlap_s: float = 0.0        # virtual flight time hidden behind decode
     confidences: List[tuple] = dataclasses.field(default_factory=list)
@@ -1666,6 +1670,8 @@ class BatchScheduler:
             "tok2": out.decisions[collm.l_ee2].token,
             "upload": out.upload,
         }
+        if out.held is not None:
+            get["held"] = out.held
         if want_logits:
             if self.mode == "standalone":
                 get["logits_l2"] = out.decisions[collm.l_ee2].logits
@@ -1682,6 +1688,8 @@ class BatchScheduler:
 
         for s in runnable:
             s.stats.tokens += 1
+            if "held" in fetched:
+                s.stats.held_expert_tokens += int(fetched["held"][s.index])
             c1 = float(confs.get(collm.l_ee1, np.zeros(self.B))[s.index])
             c2 = float(confs.get(collm.l_ee2, np.zeros(self.B))[s.index])
             s.stats.confidences.append((c1, c2))

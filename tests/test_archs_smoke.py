@@ -77,6 +77,7 @@ def test_smoke_decode_matches_teacher_forcing(arch):
     pb["tokens"] = batch["tokens"][:, :-1]
     _, _, caches, _ = model.prefill(params, pb, caches)
     pos = jnp.asarray((cfg.vision_tokens or 0) + s - 1, jnp.int32)
-    xh, _, _ = model.decode_step(params, batch["tokens"][:, -1:], caches, pos)
+    xh, _, _, _ = model.decode_step(params, batch["tokens"][:, -1:], caches,
+                                    pos)
     got = model.logits(params, xh)[:, 0]
     assert float(jnp.max(jnp.abs(got - ref))) < 2e-4

@@ -1,5 +1,6 @@
-"""MoE invariants: shard_map dispatch == local dispatch == decode gather
-(at no-drop capacity); drop behaviour bounded; router normalization."""
+"""MoE invariants: shard_map dispatch == local dispatch == the served
+dropless layer (at no-drop capacity); drop behaviour bounded; router
+normalization."""
 import dataclasses
 
 import jax
@@ -11,7 +12,7 @@ from repro.configs.base import ModelConfig, MoEConfig
 from repro.launch.mesh import make_debug_mesh
 from repro.launch.sharding import ShardingPolicy, use_policy
 from repro.models.moe import (_moe_forward_local, _moe_forward_shardmap,
-                              init_moe, moe_forward, moe_forward_decode)
+                              init_moe, moe_forward, moe_served)
 
 
 def _cfg(cf=8.0, e=4, k=2):
@@ -45,7 +46,7 @@ def test_forward_matches_decode_at_no_drop(setup):
     cfg, params, x = setup
     out_f, _ = _moe_forward_local(params, cfg, x)
     out_d = jnp.concatenate(
-        [moe_forward_decode(params, cfg, x[:, t:t + 1])
+        [moe_served(params, cfg, x[:, t:t + 1])[0]
          for t in range(x.shape[1])], axis=1)
     np.testing.assert_allclose(np.asarray(out_f), np.asarray(out_d),
                                atol=2e-5)
