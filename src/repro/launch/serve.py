@@ -289,7 +289,9 @@ def main():
     print(f"tokens={st.tokens} exits@l1={st.exits_l1} exits@l2={st.exits_l2} "
           f"cloud_requests={st.cloud_requests} "
           f"request_rate={st.request_rate:.2%}")
-    print(f"upload={st.upload_bytes/1e3:.1f}KB")
+    print(f"upload={st.upload_bytes/1e3:.1f}KB "
+          f"reply_fetch={r['reply_fetch_bytes']}B (cloud replies' drain "
+          f"to the host)")
     if model.cfg.moe is not None and args.mode != "cloud":
         print(f"held_expert_tokens={st.held_expert_tokens} (token, expert) "
               f"pairs on held experts over the edge's moe layers")

@@ -19,10 +19,10 @@ service point's *compute* half.
     one request per cloud slot, up to ``max_batch`` rows — and each wave
     is ONE masked ``cloud_step_masked`` (or ``ring_cloud_steps`` in
     backfill mode) over the pooled cache;
-  * each request's still-on-device logits fan back out through the
-    requester's own ``CloudChannel``; arrival times are priced by the
-    channels' shared ``transport.CloudServicePoint`` (the timing half),
-    so per-client latencies stay correct.
+  * each request's still-on-device logits and greedy tokens fan back
+    out through the requester's own ``CloudChannel``; arrival times are
+    priced by the channels' shared ``transport.CloudServicePoint`` (the
+    timing half), so per-client latencies stay correct.
 
 Flushes are lazy: requests queue until an engine materializes a reply
 (the reply payload carries a ``flush`` hook) or ``flush()`` is called.
@@ -549,7 +549,8 @@ class CloudBatcher:
                 lp = p // self.pool.page_size
                 if self.pool.block_table[slot, lp] == -1:
                     self._alloc(slot, lp)
-        group = {"logits": None, "np": None, "flush": self.flush}
+        group = {"logits": None, "tokens": None, "np": None,
+                 "flush": self.flush}
         self._pending.append(_Entry(device_id=device_id, slot=slot, pos=pos,
                                     packets=packets, group=group))
         self.stats.requests += 1
@@ -582,8 +583,8 @@ class CloudBatcher:
                 lp = p // self.pool.page_size
                 if self.pool.block_table[slot, lp] == -1:
                     self._alloc(slot, lp)
-        group = {"logits": None, "all": None, "np": None, "np_all": None,
-                 "flush": self.flush}
+        group = {"logits": None, "tokens": None, "all": None, "np": None,
+                 "np_all": None, "flush": self.flush}
         self._pending.append(_Entry(device_id=device_id, slot=slot,
                                     pos=packets[-1][0], packets=packets,
                                     group=group))
@@ -685,7 +686,7 @@ class CloudBatcher:
         """Drain the queue in waves: each wave serves at most one request
         per cloud slot (and at most ``max_batch`` rows) with ONE masked
         batched cloud step; every entry's reply group gets the wave's
-        still-on-device logits."""
+        still-on-device logits and greedy tokens."""
         while self._pending:
             wave, rest, seen = [], [], set()
             for e in self._pending:
@@ -720,6 +721,7 @@ class CloudBatcher:
             logits, all_logits, self.caches = self._ring_cloud_all(
                 self.params, ring, ring_pos, valid, self.caches,
                 self._block_tbl())
+            tokens = jnp.argmax(logits, axis=-1)
             for e in wave:
                 # draft replies reconcile per position; single-token
                 # groups ignore the extra key
@@ -734,12 +736,13 @@ class CloudBatcher:
                 for k in keys:
                     dense[k][e.slot] = np.asarray(pkt.hidden[k])[0]
                 pos[e.slot] = p
-            logits, self.caches = self._cloud_masked(
+            logits, tokens, self.caches = self._cloud_masked(
                 self.params, {k: jnp.asarray(v) for k, v in dense.items()},
                 self.caches, jnp.asarray(pos), jnp.asarray(mask),
                 self._block_tbl())
         for e in wave:
             e.group["logits"] = logits
+            e.group["tokens"] = tokens
         self.stats.steps += 1
         self.stats.rows += len(wave)
         self.stats.max_rows = max(self.stats.max_rows, len(wave))

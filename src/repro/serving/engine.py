@@ -520,6 +520,7 @@ class BatchScheduler:
         # profiler span ``engine.<phase>`` (docs/serving.md §Tracing)
         self.phases = Phases()
         self.late_drops = 0          # replies dropped after slot moved on
+        self.reply_fetch_bytes = 0   # bytes the reply drain copied to host
         self._idle_s = 0.0           # virtual time nobody decoded (waits)
         self._spec = bool(self.ccfg.speculative) and mode == "collm"
         # draft length of the speculative path: below-θ rows accumulate up
@@ -1800,7 +1801,8 @@ class BatchScheduler:
             logits, self.cloud_caches = self._ring_cloud(
                 self.params, ring, ring_pos, valid, self.cloud_caches,
                 self._block_tbl())
-            group = {"logits": logits, "np": None}   # materialized at drain
+            group = {"logits": logits, "tokens": jnp.argmax(logits, -1),
+                     "np": None}                     # materialized at drain
             payloads = {s.index: (group, s.index) for s in needy}
         else:
             pkts = self.cm.take_upload_batch(
@@ -1814,11 +1816,12 @@ class BatchScheduler:
                     s.cloud_pkts.append((s.pos, pkt))
                 for k in keys:
                     dense[k][s.index] = np.asarray(pkt.hidden[k])[0]
-            logits, self.cloud_caches = self._cloud_masked(
+            logits, tokens, self.cloud_caches = self._cloud_masked(
                 self.params, {k: jnp.asarray(v) for k, v in dense.items()},
                 self.cloud_caches, jnp.asarray(pos), jnp.asarray(mask),
                 self._block_tbl())
-            group = {"logits": logits, "np": None}   # materialized at drain
+            group = {"logits": logits, "tokens": tokens,
+                     "np": None}                     # materialized at drain
             payloads = {s.index: (group, s.index) for s in needy}
 
         handles = []
@@ -1936,12 +1939,12 @@ class BatchScheduler:
                     posv[s.index] = s.draft[0].pos
                     for k in keys:
                         dense[k][s.index] = np.asarray(pkt.hidden[k])[0]
-                logits, self.cloud_caches = self._cloud_masked(
+                logits, tokens, self.cloud_caches = self._cloud_masked(
                     self.params,
                     {k: jnp.asarray(v) for k, v in dense.items()},
                     self.cloud_caches, jnp.asarray(posv),
                     jnp.asarray(mask), self._block_tbl())
-                group = {"logits": logits, "all": None,
+                group = {"logits": logits, "tokens": tokens, "all": None,
                          "np": None, "np_all": None}
             else:
                 ring, ring_pos, valid = build_upload_ring(entries, self.B)
@@ -1997,28 +2000,28 @@ class BatchScheduler:
                 group["np_all"] = np.argmax(np.asarray(group["all"]),
                                             axis=-1)        # (depth, B)
             else:
-                # dense all-singles wave: depth-1 view of the final logits
-                group["np_all"] = np.argmax(
-                    np.asarray(group["logits"]), axis=-1)[None, :]
+                # dense all-singles wave: the step's own greedy tokens
+                group["np_all"] = np.asarray(group["tokens"])[None, :]
         return group["np_all"][:, row]
 
     # -- reply drain --------------------------------------------------------
     def _reply_token(self, rep) -> int:
-        """Materialize a reply group's logits (once per dispatched batch)
-        and return this row's token."""
+        """Materialize a reply group (once per dispatched batch) and
+        return this row's token: greedy fetches the wave's device-side
+        tokens, (B,) int32; sampling fetches its (B, vocab) logits."""
         group, row = rep.reply
         if group["np"] is None:
-            with self.phases.phase("cloud_fetch"):
+            greedy = self.sampler == "greedy"
+            with self.phases.phase("cloud_fetch") as span:
                 if group["logits"] is None:
                     # CloudBatcher reply: the batched cloud step is lazy so
                     # that concurrent engines' requests land in one wave —
                     # first materialization computes it
                     group["flush"]()
-                logits = np.asarray(group["logits"])
-            if self.sampler == "greedy":
-                group["np"] = np.argmax(logits, axis=-1)
-            else:
-                group["np"] = np.asarray(self._pick(logits))
+                got = np.asarray(group["tokens" if greedy else "logits"])
+                span.note(bytes=got.nbytes)
+            self.reply_fetch_bytes += got.nbytes
+            group["np"] = got if greedy else np.asarray(self._pick(got))
         return int(group["np"][row])
 
     def _hidden_s(self, pend: _Pending) -> float:
@@ -2240,6 +2243,7 @@ class BatchScheduler:
         stats: List[Optional[GenStats]] = [None] * len(requests)
         v0 = self.vnow
         self.late_drops = 0
+        self.reply_fetch_bytes = 0
         self._tick_no = 0        # forced-preemption schedules are per-run
         # a reused channel must not leak the previous run's link/service
         # virtual times (or stale in-flight replies) into this run's trace
@@ -2306,6 +2310,7 @@ def run_multi(scheds: Sequence[BatchScheduler],
     services = {}
     for s in scheds:
         s.late_drops = 0
+        s.reply_fetch_bytes = 0
         s._tick_no = 0
         s.channel.reset()
         svc = getattr(s.channel, "service", None)
@@ -2395,8 +2400,9 @@ class ServingSystem:
         to a blocking drain, and ``fallback_after`` N consecutive deadline
         misses flips a stream to standalone mode.  The result dict gains
         ``virtual_time`` (this run's virtual makespan), ``late_drops``,
-        ``channel_stats`` and ``phases`` (this run's host calls and seconds
-        per engine phase, ``BatchScheduler.phases``).
+        ``reply_fetch_bytes`` (bytes the cloud replies' drain copied to
+        the host), ``channel_stats`` and ``phases`` (this run's host calls
+        and seconds per engine phase, ``BatchScheduler.phases``).
 
         Under ``CollmConfig.preemption != "off"`` the paged pool admits
         optimistically and preempts victims when pages run dry;
@@ -2457,6 +2463,7 @@ class ServingSystem:
                 "num_slots": slots, "phases": sched.phases.snapshot(),
                 "virtual_time": sched.last_virtual_time,
                 "late_drops": sched.late_drops,
+                "reply_fetch_bytes": sched.reply_fetch_bytes,
                 "channel_stats": sched.channel.stats.as_row(),
                 "preemptions": sched.preemptions, "oops": sched.oops,
                 "adaptive": (sched._adaptive.as_row()
@@ -2550,6 +2557,8 @@ class ServingSystem:
                "per_client": flat, "cm_stats": self.cloud.cm.stats(),
                "n_engines": n, "virtual_time": makespan,
                "late_drops": sum(s.late_drops for s in scheds),
+               "reply_fetch_bytes": sum(s.reply_fetch_bytes
+                                        for s in scheds),
                "channel_stats": ch_agg.as_row(),
                # summed over the engines; a CloudBatcher wave runs inside
                # the engine whose reply drain first needs it (cloud_fetch)

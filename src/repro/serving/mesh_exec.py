@@ -16,9 +16,9 @@ One ``MeshContext`` per ``CoLLM`` owns
     constraints pin the interior (GSPMD fills in the rest).
 
 With ``cloud_mesh=None`` (the default) there is no mesh, no policy and
-no placement — ``jit_step`` degenerates to plain ``jax.jit`` and the
-single-device path is byte-for-byte what it was before this layer
-existed.
+no placement — ``jit_step`` degenerates to plain ``jax.jit`` (of the
+step, with ``REPLY_TOKENS``' argmax added) and the single-device path is
+byte-for-byte what it was before this layer existed.
 
 This module must not import ``repro.serving.engine`` or
 ``repro.serving.cloud_batcher`` (they import us).
@@ -30,6 +30,7 @@ import functools
 from typing import Any, Callable, Dict, Optional
 
 import jax
+import jax.numpy as jnp
 
 from repro.launch import sharding as shardlib
 from repro.launch.mesh import make_cloud_mesh
@@ -53,6 +54,23 @@ CLOUD_STEPS = frozenset({
 # place, so the caller must take the returned caches and never read the
 # ones it passed in again.
 DONATES_CACHES = frozenset({"cloud_step_masked"})
+
+# Steps that return ``(logits, caches)`` and whose jitted program returns
+# ``(logits, tokens, caches)``: ``tokens`` is each row's argmax, the first
+# maximum as ``np.argmax`` picks it, so a greedy reply drain fetches (B,)
+# int32 and not (B, vocab) logits.  The program keeps the step's name.
+REPLY_TOKENS = frozenset({"cloud_step_masked"})
+
+
+def _with_reply_tokens(step: Callable) -> Callable:
+    """``step`` with each row's greedy token added after its logits."""
+    @functools.wraps(step)
+    def stepped(*a, **kw):
+        logits, caches = step(*a, **kw)
+        with jax.named_scope("output_head"):
+            tokens = jnp.argmax(logits, axis=-1)
+        return logits, tokens, caches
+    return stepped
 
 
 class MeshContext:
@@ -80,6 +98,8 @@ class MeshContext:
         if cached is not None:
             return cached
         counts = self.trace_counts
+        if name in REPLY_TOKENS:
+            fn = _with_reply_tokens(fn)
 
         @functools.wraps(fn)
         def traced(*a, **kw):

@@ -9,8 +9,9 @@ seconds include those of the phases opened inside it.
 
     ph = Phases()
     with ph.phase("tick", rows=8):
-        with ph.phase("edge_fetch", rows=8):
+        with ph.phase("edge_fetch", rows=8) as span:
             ...
+            span.note(bytes=1024)
     ph.snapshot()   # {"engine.tick": {"calls": 1, "seconds": ...}, ...}
 
 docs/serving.md §Tracing lists the engine's phases.
@@ -33,9 +34,15 @@ class _Phase:
         self._name = name
         self._span = TraceAnnotation(name, **args)
 
-    def __enter__(self) -> None:
+    def __enter__(self) -> "_Phase":
         self._span.__enter__()
         self._t0 = time.perf_counter()
+        return self
+
+    def note(self, **args) -> None:
+        """Add ``args`` to the span's stats: what is known only inside
+        it, such as the bytes a fetch copied."""
+        self._span.set_metadata(**args)
 
     def __exit__(self, *exc) -> bool:
         dt = time.perf_counter() - self._t0

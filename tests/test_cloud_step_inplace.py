@@ -161,7 +161,7 @@ def test_jitted_masked_step_donates_its_caches():
     collm = CoLLM(model, CollmConfig())
     params = model.init(jax.random.PRNGKey(0))
     caches = collm.init_cloud_cache(B, MAX_SEQ)
-    _, out = jit_step(collm, "cloud_step_masked")(
+    _, _, out = jit_step(collm, "cloud_step_masked")(
         params, {"data": jnp.zeros((B, 1, cfg.d_model), jnp.float16)},
         caches, jnp.arange(B, dtype=jnp.int32), jnp.ones((B,), bool), None)
     assert all(a.is_deleted() for a in jax.tree.leaves(caches))
